@@ -54,14 +54,6 @@ def time_fn(fn, *args, min_time_s: float | None = None,
     return float(np.median(medians))
 
 
-def cost_analysis(compiled) -> dict:
-    """Normalized ``compiled.cost_analysis()`` (dict vs per-computation
-    list across jax versions) — canonical impl in launch.lowering."""
-    from repro.launch.lowering import cost_analysis_dict
-
-    return cost_analysis_dict(compiled)
-
-
 def emit(rows: list[tuple], header=("name", "us_per_call", "derived")):
     print(",".join(header))
     for r in rows:

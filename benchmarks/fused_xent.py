@@ -7,7 +7,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from benchmarks.common import cost_analysis, emit, time_fn
+from benchmarks.common import emit, time_fn
 from repro.core import twopass
 
 
@@ -31,7 +31,7 @@ def run(t=256, vocabs=(49152, 152064)):
         for name, fn in (("fused_twopass", _fused), ("unfused", _unfused)):
             jf = jax.jit(fn)
             sec = time_fn(jf, logits, labels)
-            ca = cost_analysis(jf.lower(logits, labels).compile())
+            ca = jf.lower(logits, labels).compile().cost_analysis()
             rows.append((f"fused_xent/{name}/vocab={v}",
                          round(sec * 1e6, 2),
                          f"bytes={float(ca.get('bytes accessed', 0))/1e6:.0f}MB"))
@@ -40,7 +40,7 @@ def run(t=256, vocabs=(49152, 152064)):
                          ("unfused_grad", _unfused)):
             jf = jax.jit(jax.grad(fn))
             sec = time_fn(jf, logits, labels)
-            ca = cost_analysis(jf.lower(logits, labels).compile())
+            ca = jf.lower(logits, labels).compile().cost_analysis()
             rows.append((f"fused_xent/{name}/vocab={v}",
                          round(sec * 1e6, 2),
                          f"bytes={float(ca.get('bytes accessed', 0))/1e6:.0f}MB"))

@@ -20,7 +20,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from benchmarks import common
 from benchmarks.common import emit
 from repro.core.softmax_api import SoftmaxAlgorithm, softmax as softmax_jnp
 from repro.kernels import ops
@@ -69,7 +68,7 @@ def run(n=2 ** 22):
         ratio = kernel[algo] / (3 * base)
         c = jax.jit(lambda t, a=algo: softmax_jnp(t, algorithm=a)).lower(
             x).compile()
-        cpu_bytes = float(common.cost_analysis(c).get("bytes accessed", 0))
+        cpu_bytes = float(c.cost_analysis().get("bytes accessed", 0))
         rows.append((
             f"memory_traffic/{algo.value}", 0,
             f"theory={desc}({cost}N);"

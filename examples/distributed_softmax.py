@@ -7,16 +7,15 @@ Run: XLA_FLAGS=--xla_force_host_platform_device_count=8 \
      PYTHONPATH=src python examples/distributed_softmax.py
 """
 
-import numpy as np
-
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import PartitionSpec as P
 
 from repro.core import twopass
+from repro.launch.mesh import make_mesh
 
 n_dev = len(jax.devices())
-mesh = Mesh(np.array(jax.devices()).reshape(n_dev), ("model",))
+mesh = make_mesh((n_dev,), ("model",))
 vocab = 1024 * n_dev
 x = jax.random.normal(jax.random.PRNGKey(0), (8, vocab)) * 10
 

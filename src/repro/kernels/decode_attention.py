@@ -17,12 +17,17 @@ separate XLA stages:
     page DMAs are issued from table entries instead of materializing a
     host-visible ``jnp.take`` gather of the whole slot in HBM.
 
-Grid layout (both kernels): ``(slots, Hkv, KV tiles)`` with the KV sweep
-innermost, so the per-(slot, head) accumulators ``(o, m_sum, n_sum)`` live
-in VMEM across the whole sweep (same revisited-output pattern as
-``flash_attention``).  One grid row per slot: the slot axis never tiles —
-the tunable dims are the KV tile length (``block_t``, contiguous) and the
-page count per tile (``pages_per_tile``, paged), swept by
+Grid layout: ``(slots, Hkv, KV tiles)`` for the contiguous kernel and
+``(slots, KV tiles)`` for the paged one, the KV sweep innermost either
+way, so the per-(slot, head) accumulators ``(o, m_sum, n_sum)`` live in
+VMEM across the whole sweep (same revisited-output pattern as
+``flash_attention``).  A paged grid step fetches every KV head of its
+pages: the arena is ``[P, ps, Hkv, D]``, and the TPU lowering only takes
+blocks whose last two dims are (8, 128)-aligned or whole, so a one-head
+``(ps, 1, D)`` slice of a page cannot be a block.  The head loop runs
+inside the kernel instead.  One grid row per slot: the slot axis never
+tiles — the tunable dims are the KV tile length (``block_t``, contiguous)
+and the page count per tile (``pages_per_tile``, paged), swept by
 ``repro.kernels.autotune`` through the ``decode_attention`` /
 ``decode_attention_paged`` registry ops.
 
@@ -70,9 +75,10 @@ def _grid_spec(num_scalar_prefetch, grid, in_specs, out_specs):
 
 def _mn_fold_tile(o_ref, m_ref, n_ref, q, k, v, kpos, length, *,
                   scale: float, window: int | None, j, last_j: int,
-                  k_scale=None, v_scale=None):
+                  k_scale=None, v_scale=None, h: int = 0):
     """Score one KV tile, mask it, fold it into the running (o, m, n)
-    accumulator refs, and normalize on the sweep's last step.
+    accumulator refs (head ``h`` of their block), and normalize on the
+    sweep's last step.
 
     ``q``: (G, D) f32; ``k``/``v``: (BT, D)/(BT, Dv) f32; ``kpos``: int32
     (1, BT) logical cache positions of the tile's columns (2-D for Mosaic's
@@ -113,24 +119,24 @@ def _mn_fold_tile(o_ref, m_ref, n_ref, q, k, v, kpos, length, *,
 
     @pl.when(j == 0)
     def _init():
-        o_ref[0, 0] = o_loc
-        m_ref[0, 0] = m_loc
-        n_ref[0, 0] = n_loc
+        o_ref[0, h] = o_loc
+        m_ref[0, h] = m_loc
+        n_ref[0, h] = n_loc
 
     @pl.when(j > 0)
     def _fold():
-        n_old = n_ref[0, 0]
+        n_old = n_ref[0, h]
         n_new = jnp.maximum(n_old, n_loc)
         a_old = exp2_int(n_old - n_new)              # exact 2^k rescales
         a_loc = exp2_int(n_loc - n_new)
-        o_ref[0, 0] = o_ref[0, 0] * a_old + o_loc * a_loc
-        m_ref[0, 0] = m_ref[0, 0] * a_old + m_loc * a_loc
-        n_ref[0, 0] = n_new
+        o_ref[0, h] = o_ref[0, h] * a_old + o_loc * a_loc
+        m_ref[0, h] = m_ref[0, h] * a_old + m_loc * a_loc
+        n_ref[0, h] = n_new
 
     @pl.when(j == last_j)
     def _normalize():
         # max() guard: a free slot (length 0) has m_sum == 0 -> exact zeros
-        o_ref[0, 0] = o_ref[0, 0] / jnp.maximum(m_ref[0, 0], 1e-37)
+        o_ref[0, h] = o_ref[0, h] / jnp.maximum(m_ref[0, h], 1e-37)
 
 
 def _contig_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, n_ref, *,
@@ -201,37 +207,43 @@ def decode_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
 
 def _paged_kernel(pt_ref, len_ref, q_ref, *refs, scale: float,
                   window: int | None, ps: int, ppt: int, nt: int,
-                  quant: bool = False):
+                  hkv: int, quant: bool = False):
     krefs, vrefs = refs[:ppt], refs[ppt:2 * ppt]
-    ks = vs = None
     if quant:
         # int8 arenas: the pages' fp32 scale rows ride the same
-        # scalar-prefetch gather, one (1, ps)-shaped block per page.
+        # scalar-prefetch gather, one block per page: (1, 1, ps) for
+        # "page" scales, (1, ps, Hkv) for "page_head".
         ksrefs, vsrefs = refs[2 * ppt:3 * ppt], refs[3 * ppt:4 * ppt]
         o_ref, m_ref, n_ref = refs[4 * ppt:]
-
-        def srow(r):                         # -> (1, ps) per-column scales
-            return r[...] if len(r.shape) == 2 else r[:, :, 0]
-
-        ks = jnp.concatenate([srow(r) for r in ksrefs], 1)
-        vs = jnp.concatenate([srow(r) for r in vsrefs], 1)
     else:
         o_ref, m_ref, n_ref = refs[2 * ppt:]
+
+    def srow(r, h):                          # -> (1, ps) per-column scales
+        return r[0] if r.shape[1] == 1 else r[0, :, h][None, :]
+
     s_idx = pl.program_id(0)
-    j = pl.program_id(2)
-    # Each of the tile's ppt pages arrived via its own scalar-prefetch
-    # block fetch (non-contiguous in the arena); concatenated they form
-    # the contiguous logical window [j*ppt*ps, (j+1)*ppt*ps).  On the
-    # quantized path the astype is the whole dequant story: int8 codes
-    # widen to f32 IN REGISTER, per tile — the arena itself is never
-    # copied to a full-precision buffer.
-    k = jnp.concatenate([r[0, :, 0].astype(jnp.float32) for r in krefs], 0)
-    v = jnp.concatenate([r[0, :, 0].astype(jnp.float32) for r in vrefs], 0)
+    j = pl.program_id(1)
     kpos = (j * (ppt * ps)
             + jax.lax.broadcasted_iota(jnp.int32, (1, ppt * ps), 1))
-    _mn_fold_tile(o_ref, m_ref, n_ref, q_ref[0, 0].astype(jnp.float32),
-                  k, v, kpos, len_ref[s_idx], scale=scale, window=window,
-                  j=j, last_j=nt - 1, k_scale=ks, v_scale=vs)
+    for h in range(hkv):
+        # Each of the tile's ppt pages arrived via its own scalar-prefetch
+        # block fetch (non-contiguous in the arena); concatenated they
+        # form the contiguous logical window [j*ppt*ps, (j+1)*ppt*ps).  On
+        # the quantized path the astype is the whole dequant story: int8
+        # codes widen to f32 IN REGISTER, per tile — the arena itself is
+        # never copied to a full-precision buffer.
+        k = jnp.concatenate([r[0, :, h, :].astype(jnp.float32)
+                             for r in krefs], 0)
+        v = jnp.concatenate([r[0, :, h, :].astype(jnp.float32)
+                             for r in vrefs], 0)
+        ks = vs = None
+        if quant:
+            ks = jnp.concatenate([srow(r, h) for r in ksrefs], 1)
+            vs = jnp.concatenate([srow(r, h) for r in vsrefs], 1)
+        _mn_fold_tile(o_ref, m_ref, n_ref, q_ref[0, h].astype(jnp.float32),
+                      k, v, kpos, len_ref[s_idx], scale=scale,
+                      window=window, j=j, last_j=nt - 1, k_scale=ks,
+                      v_scale=vs, h=h)
 
 
 @functools.partial(jax.jit,
@@ -250,11 +262,12 @@ def decode_attention_paged_pallas(q: jax.Array, k_pages: jax.Array,
     (``kv_cache.init_paged_pool`` layout); page_table: [S, Pmax] int32;
     lengths: [S] int32.  Both int32 operands are scalar-prefetched: the
     per-page BlockSpec index maps read ``page_table`` directly, so each
-    grid step DMAs ``pages_per_tile`` non-contiguous arena pages into VMEM
-    and attends them as one contiguous logical window.  Table entries
-    backing no valid position (free slots, pages past ``lengths``, the
-    pad below) may point anywhere in the arena — the length mask makes
-    their content invisible.  Returns [S, Hkv, G, Dv] in q.dtype.
+    grid step DMAs ``pages_per_tile`` non-contiguous arena pages (all
+    their KV heads) into VMEM and attends them, head by head, as one
+    contiguous logical window.  Table entries backing no valid position
+    (free slots, pages past ``lengths``, the pad below) may point
+    anywhere in the arena — the length mask makes their content
+    invisible.  Returns [S, Hkv, G, Dv] in q.dtype.
 
     int8 arenas pass ``k_scale``/``v_scale`` fp32 sidecars (``[P, ps]``
     "page" granularity or ``[P, ps, Hkv]`` "page_head"): each page's scale
@@ -262,7 +275,8 @@ def decode_attention_paged_pallas(q: jax.Array, k_pages: jax.Array,
     and dequantization happens inside the (m, n) fold — int8 codes widen
     to f32 in-register per tile, scales apply as per-column multipliers
     (:func:`_mn_fold_tile`); a full-precision copy of the arena is never
-    materialized in HBM or VMEM.
+    materialized in HBM or VMEM.  "page" scales are viewed as
+    ``[P, 1, ps]`` so that a page's row is a whole-dims block.
     """
     s, hkv, g, d = q.shape
     ps = k_pages.shape[1]
@@ -277,42 +291,38 @@ def decode_attention_paged_pallas(q: jax.Array, k_pages: jax.Array,
         page_table = jnp.pad(page_table, ((0, 0), (0, ppad - pmax)))
     nt = ppad // ppt
 
-    def page_spec(i, width):
+    def page_spec(i, block):
         return pl.BlockSpec(
-            (1, ps, 1, width),
-            lambda si, h, j, tab, ln, i=i: (tab[si, j * ppt + i], 0, h, 0))
-
-    def scale_spec(i, leaf):
-        if leaf.ndim == 2:                           # [P, ps] "page"
-            return pl.BlockSpec(
-                (1, ps),
-                lambda si, h, j, tab, ln, i=i: (tab[si, j * ppt + i], 0))
-        return pl.BlockSpec(                         # [P, ps, Hkv]
-            (1, ps, 1),
-            lambda si, h, j, tab, ln, i=i: (tab[si, j * ppt + i], 0, h))
+            block,
+            lambda si, j, tab, ln, i=i: (tab[si, j * ppt + i],)
+            + (0,) * (len(block) - 1))
 
     kernel = functools.partial(_paged_kernel, scale=scale, window=window,
-                               ps=ps, ppt=ppt, nt=nt, quant=quant)
+                               ps=ps, ppt=ppt, nt=nt, hkv=hkv, quant=quant)
     scale_specs, scale_args = [], ()
     if quant:
-        scale_specs = ([scale_spec(i, k_scale) for i in range(ppt)]
-                       + [scale_spec(i, v_scale) for i in range(ppt)])
+        if k_scale.ndim == 2:                        # [P, ps] "page"
+            k_scale = k_scale[:, None, :]
+            v_scale = v_scale[:, None, :]
+        blk = (1,) + k_scale.shape[1:]
+        scale_specs = [page_spec(i, blk) for i in range(ppt)] * 2
         scale_args = (*([k_scale] * ppt), *([v_scale] * ppt))
+    head_spec = pl.BlockSpec((1, hkv, g, d),
+                             lambda si, j, tab, ln: (si, 0, 0, 0))
     grid_spec = _grid_spec(
-        2, (s, hkv, nt),
+        2, (s, nt),
         in_specs=(
-            [pl.BlockSpec((1, 1, g, d),
-                          lambda si, h, j, tab, ln: (si, h, 0, 0))]
-            + [page_spec(i, d) for i in range(ppt)]
-            + [page_spec(i, dv) for i in range(ppt)]
+            [head_spec]
+            + [page_spec(i, (1, ps, hkv, d)) for i in range(ppt)]
+            + [page_spec(i, (1, ps, hkv, dv)) for i in range(ppt)]
             + scale_specs),
         out_specs=[
-            pl.BlockSpec((1, 1, g, dv),
-                         lambda si, h, j, tab, ln: (si, h, 0, 0)),
-            pl.BlockSpec((1, 1, g, 1),
-                         lambda si, h, j, tab, ln: (si, h, 0, 0)),
-            pl.BlockSpec((1, 1, g, 1),
-                         lambda si, h, j, tab, ln: (si, h, 0, 0)),
+            pl.BlockSpec((1, hkv, g, dv),
+                         lambda si, j, tab, ln: (si, 0, 0, 0)),
+            pl.BlockSpec((1, hkv, g, 1),
+                         lambda si, j, tab, ln: (si, 0, 0, 0)),
+            pl.BlockSpec((1, hkv, g, 1),
+                         lambda si, j, tab, ln: (si, 0, 0, 0)),
         ])
     o, _, _ = pl.pallas_call(
         kernel,
@@ -323,7 +333,7 @@ def decode_attention_paged_pallas(q: jax.Array, k_pages: jax.Array,
             jax.ShapeDtypeStruct((s, hkv, g, 1), jnp.float32),
         ],
         interpret=_interpret(),
-        **_tpu_params(("parallel", "parallel", "arbitrary")),
+        **_tpu_params(("parallel", "arbitrary")),
     )(page_table.astype(jnp.int32), lengths.astype(jnp.int32),
       q, *([k_pages] * ppt), *([v_pages] * ppt), *scale_args)
     return o.astype(q.dtype)

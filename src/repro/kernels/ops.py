@@ -17,7 +17,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.softmax_api import SoftmaxAlgorithm
@@ -96,17 +95,28 @@ def softmax(x: jax.Array,
 
 
 def _softmax_padded(x, algorithm, block_rows, block_cols, policy):
+    from repro.distributed import autoshard  # lazy: kernels ↛ distributed
+
     x2, lead = _as_rows(x)
     rows, cols = x2.shape
     br, bc = _blocks("softmax", rows, cols, x.dtype, block_rows, block_cols,
                      policy)
-    pr, pc = _round_up(rows, br), _round_up(cols, bc)
+    mesh = autoshard.active_mesh()
+    n_dev = 1 if mesh is None else mesh.size
+    pr, pc = _round_up(rows, br * n_dev), _round_up(cols, bc)
     padded = jnp.full((pr, pc), -jnp.inf, x2.dtype)
     # Padded rows are all -inf: harmless garbage, sliced away below.  Padded
     # cols are -inf: exact (m=0) zero of the monoid / exp(-inf)=0 for Alg 1/2.
     padded = jax.lax.dynamic_update_slice(padded, x2, (0, 0))
-    y = _SOFTMAX_2D[algorithm](padded, block_rows=br, block_cols=bc)
-    return y[:rows, :cols].reshape(*lead, cols)
+    fn = functools.partial(_SOFTMAX_2D[algorithm], block_rows=br,
+                           block_cols=bc)
+    if n_dev > 1:
+        # XLA cannot partition a Pallas kernel: under a mesh the rows (each
+        # an independent softmax) split over every device.
+        spec = P(mesh.axis_names, None)
+        fn = jax.shard_map(fn, mesh=mesh, in_specs=spec, out_specs=spec,
+                           check_vma=False)
+    return fn(padded)[:rows, :cols].reshape(*lead, cols)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4))
@@ -194,8 +204,10 @@ def _lmhead_ref_loss(h, w, labels):
 def _lmhead_blocks(h, w, block_t, block_v, policy):
     t, v = h.shape[0], w.shape[1]
     shards, _ = _tp_shards(v)
-    return _blocks("lmhead_xent", t, v, h.dtype, block_t, block_v, policy,
-                   shards=shards)
+    bt, bv = _blocks("lmhead_xent", t, v, h.dtype, block_t, block_v, policy,
+                     shards=shards)
+    return _xent.fit_lmhead_blocks(bt, bv, h.shape[1],
+                                   max(h.dtype.itemsize, w.dtype.itemsize))
 
 
 def _lmhead_chunks(v, bv):
@@ -916,8 +928,9 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         if shards > 1:
             # Head axis (dim 1 of q/k/v) over model; lengths replicated.
             hs = P(None, "model", None, None)
-            fn = shard_map(fn, mesh=mesh, in_specs=(hs, hs, hs, P(None)),
-                           out_specs=hs, check_rep=False)
+            fn = jax.shard_map(fn, mesh=mesh,
+                               in_specs=(hs, hs, hs, P(None)),
+                               out_specs=hs, check_vma=False)
         return fn(q, k, v, lengths)
     return _decode_attention_chunked(
         q, k, v, lengths, scale=scale, window=window,
@@ -992,13 +1005,13 @@ def decode_attention_paged(q: jax.Array, k_pages: jax.Array,
                 one = (P(None, None) if k_scale.ndim == 2
                        else P(None, None, "model"))
                 sc_spec = (one, one)
-            fn = shard_map(
+            fn = jax.shard_map(
                 fn, mesh=mesh,
                 in_specs=(P(None, "model", None, None),
                           P(None, None, "model", None),
                           P(None, None, "model", None),
                           P(None, None), P(None)) + sc_spec,
-                out_specs=P(None, "model", None, None), check_rep=False)
+                out_specs=P(None, "model", None, None), check_vma=False)
         if k_scale is not None:
             return fn(q, k_pages, v_pages, page_table, lengths, k_scale,
                       v_scale)

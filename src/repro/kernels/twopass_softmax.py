@@ -32,16 +32,17 @@ def _interpret() -> bool:
     return jax.default_backend() == "cpu"
 
 
-def _tpu_params(dims: tuple[str, ...]) -> dict:
-    """dimension_semantics for the real-TPU lowering (no-op in interpret)."""
+def _tpu_params(dims: tuple[str, ...],
+                vmem_limit_bytes: int | None = None) -> dict:
+    """dimension_semantics (and, where a kernel's blocks outgrow the
+    compiler's default scoped VMEM, its limit) for the real-TPU lowering;
+    no-op in interpret mode."""
     if _interpret():
         return {}
     from jax.experimental.pallas import tpu as pltpu  # noqa: PLC0415
 
-    # renamed TPUCompilerParams -> CompilerParams across jax releases
-    params_cls = getattr(pltpu, "CompilerParams", None) or \
-        pltpu.TPUCompilerParams
-    return {"compiler_params": params_cls(dimension_semantics=dims)}
+    return {"compiler_params": pltpu.CompilerParams(
+        dimension_semantics=dims, vmem_limit_bytes=vmem_limit_bytes)}
 
 
 def _pass1_kernel(x_ref, m_ref, n_ref):

@@ -29,6 +29,41 @@ from repro.kernels.twopass_softmax import _interpret, _tpu_params
 DEFAULT_BLOCK_T = 256
 DEFAULT_BLOCK_V = 512
 
+# The fused LM-head kernels hold a whole (block_t, d) hidden slab and a
+# (d, block_v) weight slab per grid step, so their VMEM grows with the
+# hidden width: at d=3840 even 256x512 tiles outgrow the compiler's default
+# scoped limit.  They run under this raised limit (a TPU v5e core has 128
+# MiB of VMEM), with tiles shrunk by :func:`fit_lmhead_blocks` until the
+# estimate below fits LMHEAD_VMEM_BUDGET.
+LMHEAD_VMEM_LIMIT = 100 << 20
+LMHEAD_VMEM_BUDGET = 72 << 20
+
+
+def lmhead_vmem_bytes(block_t: int, block_v: int, d: int,
+                      itemsize: int) -> int:
+    """Upper estimate of one lmhead grid step's VMEM: h/w input blocks
+    (double-buffered), the widest f32 output block (dh's (block_t, d) or
+    dw's (d, block_v), double-buffered), the in-kernel f32 casts of h and
+    w, and three (block_t, block_v) f32 tiles (logits, p, dlogits)."""
+    slabs = block_t * d + d * block_v
+    return (2 * slabs * itemsize + 2 * d * max(block_t, block_v) * 4
+            + slabs * 4 + 3 * block_t * block_v * 4)
+
+
+def fit_lmhead_blocks(block_t: int, block_v: int, d: int,
+                      itemsize: int) -> tuple[int, int]:
+    """Halve block_v, then block_t (floors 128 and 8), until
+    :func:`lmhead_vmem_bytes` fits LMHEAD_VMEM_BUDGET."""
+    while lmhead_vmem_bytes(block_t, block_v, d, itemsize) > \
+            LMHEAD_VMEM_BUDGET:
+        if block_v > 128:
+            block_v //= 2
+        elif block_t > 8:
+            block_t //= 2
+        else:
+            break
+    return block_t, block_v
+
 
 def _fwd_kernel(x_ref, lab_ref, m_ref, n_ref, ll_ref, *, block_v: int):
     """Pass 1: fold tile into (m_sum, n_sum) and gather the label logit."""
@@ -262,7 +297,7 @@ def lmhead_xent_fwd_2d(h: jax.Array, w: jax.Array, labels: jax.Array,
                    _stat_spec(block_t)],
         out_shape=[jax.ShapeDtypeStruct((t, 1), jnp.float32)] * 3,
         interpret=_interpret(),
-        **_tpu_params(("parallel", "arbitrary")),
+        **_tpu_params(("parallel", "arbitrary"), LMHEAD_VMEM_LIMIT),
     )(h, w, labels.astype(jnp.int32)[:, None])
 
     ln2 = jnp.float32(LN2_HI + LN2_LO)
@@ -294,7 +329,7 @@ def lmhead_xent_dh_2d(h: jax.Array, w: jax.Array, labels: jax.Array,
         out_specs=pl.BlockSpec((block_t, d), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((t, d), jnp.float32),
         interpret=_interpret(),
-        **_tpu_params(("parallel", "arbitrary")),
+        **_tpu_params(("parallel", "arbitrary"), LMHEAD_VMEM_LIMIT),
     )(h, w, labels.astype(jnp.int32)[:, None], m_sum, n_sum,
       dloss.astype(jnp.float32)[:, None])
 
@@ -325,6 +360,6 @@ def lmhead_xent_dw_2d(h: jax.Array, w: jax.Array, labels: jax.Array,
         out_specs=pl.BlockSpec((d, block_v), lambda j, i: (0, j)),
         out_shape=jax.ShapeDtypeStruct((d, v), jnp.float32),
         interpret=_interpret(),
-        **_tpu_params(("parallel", "arbitrary")),
+        **_tpu_params(("parallel", "arbitrary"), LMHEAD_VMEM_LIMIT),
     )(h, w, labels.astype(jnp.int32)[:, None], m_sum, n_sum,
       dloss.astype(jnp.float32)[:, None])

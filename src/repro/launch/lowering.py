@@ -48,15 +48,6 @@ _COLL_RE = re.compile(
 _SHAPE_RE = re.compile(r"([a-z0-9]+)\[([0-9,]*)\]")
 
 
-def cost_analysis_dict(compiled) -> dict:
-    """Normalized cost_analysis(): dict in recent jax, per-computation list
-    in others.  Canonical impl — benchmarks.common delegates here."""
-    ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
-
-
 def _type_bytes(type_str: str) -> int:
     total = 0
     for dt, dims in _SHAPE_RE.findall(type_str):
@@ -198,7 +189,7 @@ def lower_and_analyze(arch: str, cell: ShapeCell | str, mesh, *,
         lowered = jitted.lower(*args)
         compiled = lowered.compile()
         ma = compiled.memory_analysis()
-        ca = cost_analysis_dict(compiled)
+        ca = compiled.cost_analysis()
         coll = collective_bytes(compiled.as_text())
 
     result = {
@@ -235,7 +226,7 @@ def extrapolate_cost(arch: str, cell: ShapeCell | str, mesh, **kw) -> dict:
             jitted, args = build_cell(arch, cell, mesh,
                                       unrolled_layers=lcount, **kw)
             compiled = jitted.lower(*args).compile()
-            ca = cost_analysis_dict(compiled)
+            ca = compiled.cost_analysis()
             coll = collective_bytes(compiled.as_text())
             vals[lcount] = {
                 "flops": float(ca.get("flops", 0.0)),

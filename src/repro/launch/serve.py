@@ -16,11 +16,54 @@ engine's streaming generator — tokens print as decode bursts complete
 instead of after the run.  Only vlm (prompts carry patch inputs the
 scheduler has no Request field for) still falls back to a phase-timed
 lockstep prefill+decode loop.
+
+The platform picks the implementation, not a flag: on a TPU every softmax
+site runs its Pallas kernel (``use_kernels``), elsewhere the jnp (m, n)
+forms.  Params are held in the compute dtype (bf16 at published widths:
+h2o-danube-3-4b's 3.96 B params take 7.9 GB instead of 15.9 GB in f32).
 """
 
 from __future__ import annotations
 
 import argparse
+import dataclasses
+
+
+def load_model(arch: str, *, reduced: bool = False, mesh=None,
+               softmax: str = "two_pass"):
+    """``(model, params)`` as the serve launcher runs them: kernels on where
+    the backend is a TPU, params in the compute dtype and initialised by
+    one jitted ``model.init``.  Under ``mesh`` the params are initialised
+    straight into their tensor-parallel shardings
+    (``param_specs(fsdp=False)`` of ``model.init_shape()``), so no device
+    ever holds the whole model."""
+    import jax
+
+    from repro.distributed import sharding
+    from repro.launch.mesh import mesh_tp
+    from repro.models import build_model
+
+    model = build_model(arch, tp=1 if mesh is None else mesh_tp(mesh),
+                        reduced=reduced, softmax_algorithm=softmax,
+                        use_kernels=jax.default_backend() == "tpu")
+    model.cfg = dataclasses.replace(model.cfg, param_dtype=model.cfg.dtype)
+    out = None
+    if mesh is not None:
+        specs = sharding.param_specs(model.init_shape(), model.cfg, mesh,
+                                     fsdp=False)
+        out = sharding.named(specs, mesh)
+    params = jax.jit(model.init, out_shardings=out)(jax.random.PRNGKey(0))
+    return model, params
+
+
+def build_engine(arch: str, *, reduced: bool = False, mesh=None,
+                 softmax: str = "two_pass", **engine_kw):
+    """The continuous-batching engine ``python -m repro.launch.serve``
+    serves with: :func:`load_model`, then ``model.serving_engine`` with
+    ``engine_kw`` (slots, max_len, temperature, pool options)."""
+    model, params = load_model(arch, reduced=reduced, mesh=mesh,
+                               softmax=softmax)
+    return model.serving_engine(params, mesh=mesh, **engine_kw)
 
 
 def main():
@@ -90,10 +133,11 @@ def main():
 
     import jax
 
-    from repro.models import build_model
+    from repro.configs import get_config
+    from repro.launch.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     mesh = None
-    tp = 1
     if args.mesh is not None:
         from repro.launch.mesh import make_serving_mesh
 
@@ -102,21 +146,18 @@ def main():
         except ValueError:
             p.error("--mesh wants DATAxMODEL, e.g. 2x4")
         mesh = make_serving_mesh((d, m))
-        tp = m
         print(f"mesh: {d}x{m} over {jax.device_count()} devices "
               f"(axes data={d}, model={m})")
 
-    model = build_model(args.arch, tp=tp, reduced=args.reduced,
-                        softmax_algorithm=args.softmax)
-    cfg = model.cfg
-    params = model.init(jax.random.PRNGKey(0))
     key = jax.random.PRNGKey(1)
-
-    if cfg.family == "vlm":
+    if get_config(args.arch).family == "vlm":
         # No continuous-batching path (prompts carry patch inputs the
         # scheduler has no Request field for) — lockstep loop, phase-timed.
         from repro.serving import engine
 
+        model, params = load_model(args.arch, reduced=args.reduced,
+                                   mesh=mesh, softmax=args.softmax)
+        cfg = model.cfg
         prompt = jax.random.randint(key, (args.slots, args.prompt_len), 0,
                                     cfg.vocab)
         kw = {"patches": jax.random.normal(
@@ -130,20 +171,21 @@ def main():
     else:
         from repro.serving.scheduler import Request
 
-        encdec = cfg.family == "encdec"
+        encdec = get_config(args.arch).family == "encdec"
         n_frames = args.enc_frames or args.prompt_len
-        eng = model.serving_engine(
-            params, slots=args.slots,
-            max_len=args.prompt_len + args.steps + 8,
+        eng = build_engine(
+            args.arch, reduced=args.reduced, mesh=mesh, softmax=args.softmax,
+            slots=args.slots, max_len=args.prompt_len + args.steps + 8,
             temperature=args.temperature, seed=2,
             paged=False if args.strip else "auto",
             page_size=args.page_size, pages=args.pages,
             prefix_cache=False if args.no_prefix_cache else "auto",
-            mesh=mesh, page_dtype=args.kv_dtype,
+            page_dtype=args.kv_dtype,
             scale_granularity=args.scale_granularity,
             host_swap_bytes=args.host_swap_bytes,
             **(dict(max_cross_len=n_frames, enc_chunk=args.enc_chunk)
                if encdec else {}))
+        cfg = eng.cfg
         rng = np.random.default_rng(0)
         arrivals = (np.cumsum(rng.exponential(1.0 / args.arrival_rate,
                                               args.requests))

@@ -35,10 +35,12 @@ def main():
                         format="%(asctime)s %(levelname)s %(message)s")
 
     from repro.configs.base import SHAPES, ShapeCell
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.mesh import make_mesh
     from repro.models import build_model
     from repro.training.trainer import Trainer, TrainerConfig
 
+    enable_compile_cache()
     mesh = None
     tp = 1
     if args.mesh:

@@ -350,23 +350,29 @@ class ContinuousBatchingEngine:
         # Pool-returning jits are pinned with ``out_shardings`` under a
         # mesh: the arena layout must survive every step or XLA would be
         # free to re-lay the pool out (resharding the whole arena) per
-        # call.  Tokens/keys are tiny and stay replicated.
+        # call.  Tokens/keys are tiny and stay replicated.  Every
+        # pool-returning jit donates the pool: the arena is updated in place
+        # instead of copied per call, so neither a run-ahead burst nor a
+        # round of admissions holds one arena version per in-flight call.
         if mesh is not None:
             pool_sh = dist_sharding.named(
                 dist_sharding.pool_specs(self.pool, cfg, mesh), mesh)
             rep = NamedSharding(mesh, PartitionSpec())
             self._step = self._with_mesh(jax.jit(
-                _fused_decode, out_shardings=(rep, pool_sh, rep)))
+                _fused_decode, out_shardings=(rep, pool_sh, rep),
+                donate_argnums=(1,)))
         else:
             pool_sh = None
-            self._step = jax.jit(_fused_decode)
+            self._step = jax.jit(_fused_decode, donate_argnums=(1,))
         # prefill jits are cached per cache-allocation length (one compile
         # per prompt bucket); see _prefill_fn.  Tail prefills (prefix hits)
         # cache per (allocation, tail-bucket) pair — see _extend_fn.
         self._prefill_fns: dict[int, object] = {}
         self._extend_fns: dict[tuple, object] = {}
         self._prefill_shapes: set[tuple] = set()
-        pool_kw = {} if pool_sh is None else dict(out_shardings=pool_sh)
+        pool_kw = dict(donate_argnums=(0,))
+        if pool_sh is not None:
+            pool_kw["out_shardings"] = pool_sh
         if self.paged:
             self._adopt = self._with_mesh(
                 jax.jit(kv_cache.adopt_slot_paged, **pool_kw))

@@ -57,8 +57,10 @@ class TestCheckpointer:
         m, state = _tiny_state()
         ck = Checkpointer(tmp_path)
         ck.save(5, state, blocking=True)
-        mesh = jax.make_mesh((1,), ("model",))
         from repro.distributed import sharding as shd
+        from repro.launch.mesh import make_mesh
+
+        mesh = make_mesh((1,), ("model",), devices=jax.devices()[:1])
 
         pspecs = shd.param_specs(state.params, m.cfg, mesh)
         sspecs = train_state.state_specs(pspecs)

@@ -219,16 +219,13 @@ class TestShardedCombine:
         # Emulate the shard decomposition manually (associativity already
         # hypothesis-tested); here check the exact shard_map code path on a
         # 1-device mesh.
-        from jax.sharding import Mesh, PartitionSpec as P
+        from jax.sharding import PartitionSpec as P
 
-        try:
-            shard_map = jax.shard_map            # jax >= 0.5
-        except AttributeError:
-            from jax.experimental.shard_map import shard_map
+        from repro.launch.mesh import make_mesh
 
-        mesh = Mesh(np.array(jax.devices()[:1]).reshape(1), ("model",))
+        mesh = make_mesh((1,), ("model",), devices=jax.devices()[:1])
         x = jax.random.normal(jax.random.PRNGKey(0), (4, 256)) * 10
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda xl: twopass.twopass_softmax_sharded(xl, "model"),
             mesh=mesh, in_specs=P(None, "model"), out_specs=P(None, "model"))
         np.testing.assert_allclose(np.asarray(fn(x)),

@@ -33,7 +33,8 @@ class TestShardingRules:
             from repro.models.model_zoo import Model
             from repro.distributed import sharding
             logging.basicConfig(level=logging.WARNING)
-            mesh = jax.make_mesh((2, 4), ("data", "model"))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((2, 4), ("data", "model"))
             for arch in ARCH_IDS:
                 cfg = get_config(arch).reduced()
                 import dataclasses
@@ -58,7 +59,8 @@ class TestShardingRules:
             from repro.configs.base import ShapeCell
             from repro.launch.lowering import build_cell, collective_bytes
             from repro.distributed import autoshard
-            mesh = jax.make_mesh((2, 4), ("data", "model"))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((2, 4), ("data", "model"))
             cell = ShapeCell("t", 64, 16, "{kind}")
             with mesh, autoshard.hints(mesh):
                 jitted, args = build_cell("granite-20b", cell, mesh,
@@ -167,7 +169,8 @@ class TestSeqParallelDecode:
             from repro.distributed import sharding, autoshard
             from repro.serving import kv_cache, engine
 
-            mesh = jax.make_mesh((2, 4), ("data", "model"))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((2, 4), ("data", "model"))
             base = get_config("qwen2.5-14b").reduced()
             base = dataclasses.replace(base, n_kv_heads=2, n_heads=4)
             results = {}

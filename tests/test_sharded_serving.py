@@ -181,6 +181,40 @@ class TestShardedEngineParity:
         """)
         assert "DENSE_PARITY_OK" in out
 
+    def test_launcher_inits_params_into_their_shardings(self):
+        """``launch.serve.build_engine`` under a mesh initialises params
+        straight into ``param_specs(fsdp=False)``, and serves the same
+        greedy tokens as the single-device launcher."""
+        out = _run("""
+            import jax
+            from repro.distributed import sharding
+            from repro.launch.mesh import make_serving_mesh
+            from repro.launch.serve import build_engine
+            from repro.serving.scheduler import Request
+
+            def serve(mesh):
+                eng = build_engine("qwen2.5-14b", reduced=True, mesh=mesh,
+                                   slots=2, max_len=40, temperature=0.0,
+                                   page_size=8)
+                reqs = [Request(rid=i, prompt=tuple(range(3, 12 + i)),
+                                max_new_tokens=5) for i in range(3)]
+                return [tuple(c.tokens) for c in eng.run(reqs)], eng
+
+            mesh = make_serving_mesh((1, 4))
+            t0, _ = serve(None)
+            t1, eng = serve(mesh)
+            assert t0 == t1, (t0, t1)
+            want = sharding.named(sharding.param_specs(
+                eng.model.init_shape(), eng.cfg, mesh, fsdp=False), mesh)
+            got = jax.tree.map(lambda x: x.sharding, eng.params)
+            assert jax.tree.leaves(got) == jax.tree.leaves(want)
+            assert any(len(x.sharding.device_set) == 4
+                       and not x.sharding.is_fully_replicated
+                       for x in jax.tree.leaves(eng.params))
+            print("SHARDED_INIT_OK")
+        """)
+        assert "SHARDED_INIT_OK" in out
+
     @pytest.mark.slow
     def test_mla_parity_replicated_pool(self):
         """MLA (latent-cache) family under the same mesh: pool replicated,
