@@ -65,6 +65,7 @@ from repro.distributed import sharding as dist_sharding
 from repro.models import transformer
 from repro.serving import engine, kv_cache
 from repro.serving.prefix_cache import PrefixCache
+from repro.serving.spans import Spans
 
 # families whose prefill is position-local: a pad tail past the true
 # prompt cannot influence earlier positions, so it stays invisible behind
@@ -106,7 +107,8 @@ class Request:
     what it produced instead of raising.  ``frames`` (encdec only) are
     the request's encoder frame embeddings ``[T_enc, d_model]``; they
     travel with the request through preemption so readmission can
-    re-encode.
+    re-encode.  ``queued_ns`` is stamped by ``submit()`` (or the requeue)
+    on ``time.perf_counter_ns``: the start of its queue wait and TTFT.
     """
     rid: int
     prompt: tuple[int, ...]            # prompt token ids
@@ -114,6 +116,7 @@ class Request:
     arrival_s: float = 0.0             # offset from ``run()`` start
     resumed: bool = False              # requeued after a page preemption
     frames: np.ndarray | None = None   # encdec: [T_enc, d_model] embeddings
+    queued_ns: int | None = field(default=None, repr=False)
 
     def __post_init__(self):
         self.prompt = tuple(int(t) for t in self.prompt)
@@ -145,7 +148,9 @@ class Completion:
     seq: int = 0             # admission order (preemption picks the latest)
     ttft_s: float | None = None   # wall seconds offer -> first token (the
     #                               headline metric prefix sharing moves);
-    #                               survives preemption (first admission's)
+    #                               survives preemption (first admission's).
+    #                               Offer = the submit() stamp, or under
+    #                               run()/stream() the arrival if later
 
 
 class ContinuousBatchingEngine:
@@ -409,6 +414,9 @@ class ContinuousBatchingEngine:
         self._carried: dict[int, tuple[int, list[int], float | None]] = {}
         self._admit_seq = 0
         self._run_start: float | None = None
+        # host-side phase spans (serving/spans.py); off unless a caller
+        # sets ``spans.on``
+        self.spans = Spans()
         # phase-separated throughput accounting (the satellite ask: a single
         # aggregate hides which phase the bandwidth argument is about)
         self.stats = dict(prefill_tokens=0, prefill_s=0.0, decode_tokens=0,
@@ -602,6 +610,7 @@ class ContinuousBatchingEngine:
                 f"request {req.rid}: prompt {plen} needs "
                 f"{need} pages; the pool has "
                 f"{self.allocator.usable_pages} (page_size {self.page_size})")
+        req.queued_ns = time.perf_counter_ns()
         self.pending.append(req)
         self.pending.sort(key=lambda r: r.arrival_s)
 
@@ -657,11 +666,39 @@ class ContinuousBatchingEngine:
         self.pool = self._free(self.pool, np.int32(slot))
 
     # -- admission: prefill into a free slot ---------------------------------
+    def _offered_ns(self, req: Request) -> int:
+        """When ``req`` was offered, on ``time.perf_counter_ns``: its submit
+        or requeue stamp, or under run()/stream() its arrival if that is
+        later.  Its queue wait and its TTFT both start here."""
+        t = req.queued_ns
+        if self._run_start is not None:
+            t = max(t, int((self._run_start + req.arrival_s) * 1e9))
+        return t
+
+    def _admit_span(self, rid: int, n: int = 0):
+        """A ``sched.admit`` span, ``stalled`` when some slot was decoding
+        as it began: that slot's next token waits for the admission."""
+        return self.spans.span(
+            "sched.admit", rid=rid, n=n,
+            stalled=self.spans.on and bool(self.active_slots()))
+
+    def _dequeued(self, req: Request, admit) -> None:
+        """Record the queue wait that ``admit`` (the open ``sched.admit``
+        span that takes ``req``) ends."""
+        if self.spans.on:
+            self.spans.record("sched.queue", self._offered_ns(req),
+                              admit.start_ns, rid=req.rid)
+
     def _admit(self, req: Request, slot: int, now: float) -> bool:
         """Prefill ``req`` into ``slot``.  Returns False (nothing consumed)
         when the page pool cannot back the prompt right now."""
-        if self.cfg.family == "encdec":
-            return self._admit_encdec(req, slot, now)
+        with self._admit_span(req.rid) as admit:
+            if self.cfg.family == "encdec":
+                return self._admit_encdec(req, slot, now, admit)
+            return self._admit_prefill(req, slot, now, admit)
+
+    def _admit_prefill(self, req: Request, slot: int, now: float,
+                       admit) -> bool:
         plen = len(req.prompt)
         if plen + req.max_new_tokens > self.max_len:
             raise ValueError(
@@ -686,8 +723,9 @@ class ContinuousBatchingEngine:
                     f"the pool has {self.allocator.usable_pages} "
                     f"(page_size {self.page_size})")
             if self.prefix_cache is not None:
-                match, m_tok, tail_bucket = self._plan_prefix(req.prompt,
-                                                              alloc_len)
+                with self.spans.span("prefix.match", rid=req.rid) as matched:
+                    match, m_tok, tail_bucket = self._plan_prefix(
+                        req.prompt, alloc_len)
             n_shared = len(match.pages) if match is not None else 0
             if n_shared:
                 # take the slot's references FIRST: pins the matched pages
@@ -728,6 +766,7 @@ class ContinuousBatchingEngine:
             self._note_peak()
             self.stats["prefix_hits"] += 1
             self.stats["prefix_tokens_reused"] += m_tok
+            matched.n = m_tok
             if match.partial is not None:
                 self.stats["cow_copies"] += 1
         else:
@@ -746,20 +785,22 @@ class ContinuousBatchingEngine:
                 self.pool = self._adopt(self.pool, cache, np.int32(slot),
                                         np.int32(plen))
         if self.prefix_cache is not None:
-            self.prefix_cache.insert(
-                req.prompt, self.slot_pages[slot][:self._pages_for(plen)])
+            with self.spans.span("prefix.insert", rid=req.rid):
+                self.prefix_cache.insert(
+                    req.prompt, self.slot_pages[slot][:self._pages_for(plen)])
         tok = int(jax.block_until_ready(tok)[0])
         t1 = time.perf_counter()
         self.stats["prefill_s"] += t1 - t0
         self.stats["prefill_tokens"] += plen
         self.stats["admitted"] += 1
         self._admit_seq += 1
+        admit.n = plen
+        self._dequeued(req, admit)
 
         comp = Completion(rid=req.rid, slot=slot, prompt_len=plen,
                           max_new_tokens=req.max_new_tokens, admitted_s=now,
                           seq=self._admit_seq)
-        comp.ttft_s = (max(0.0, t1 - self._run_start - req.arrival_s)
-                       if self._run_start is not None else t1 - t0)
+        comp.ttft_s = max(0.0, t1 - self._offered_ns(req) * 1e-9)
         self.slot_owner[slot] = comp
         self.slot_req[slot] = req
         comp.tokens.append(tok)
@@ -767,7 +808,8 @@ class ContinuousBatchingEngine:
         self._maybe_retire(slot, now)        # max_new_tokens == 1 edge
         return True
 
-    def _admit_encdec(self, req: Request, slot: int, now: float) -> bool:
+    def _admit_encdec(self, req: Request, slot: int, now: float,
+                      admit) -> bool:
         """encdec admission: reserve self + cross pages up-front (one
         all-or-nothing allocation), then encode the frames — wholesale, or
         one ``enc_chunk`` window per scheduler step so a long request
@@ -800,16 +842,17 @@ class ContinuousBatchingEngine:
         page_ids = self._alloc_pages(need)
         if page_ids is None:
             return False
+        self._dequeued(req, admit)
         n_self = self._pages_for(plen)
         self.slot_pages[slot] = page_ids[:n_self]
         self.slot_cross_pages[slot] = page_ids[n_self:]
-        ent = dict(req=req, parts=[], off=0, t0=time.perf_counter(),
-                   admit_s=now)
+        ent = dict(req=req, parts=[], off=0, admit_s=now)
         if self.enc_chunk is None:
             t0 = time.perf_counter()
             enc = self._encode(self.params, jnp.asarray(req.frames)[None])
             self.stats["prefill_s"] += time.perf_counter() - t0
             self._finish_encdec(slot, ent, enc, now)
+            admit.n = plen + t_enc
         else:
             self._encoding[slot] = ent
         return True
@@ -820,22 +863,26 @@ class ContinuousBatchingEngine:
         Each window is encoded independently — bidirectional attention
         within the window only, real-time streaming-encoder semantics —
         and the windows are concatenated on the position axis when the
-        last one lands."""
+        last one lands.  Each window is a ``sched.admit`` span whose
+        ``n`` counts its frames, and the prompt too in the last one."""
         for slot in list(self._encoding):
             ent = self._encoding[slot]
-            frames = ent["req"].frames
-            t_enc = int(frames.shape[0])
-            t0 = time.perf_counter()
+            req = ent["req"]
+            t_enc = int(req.frames.shape[0])
             end = min(t_enc, ent["off"] + self.enc_chunk)
-            part = self._encode(self.params,
-                                jnp.asarray(frames[ent["off"]:end])[None])
-            ent["parts"].append(part)
-            ent["off"] = end
-            self.stats["prefill_s"] += time.perf_counter() - t0
-            if end >= t_enc:
-                del self._encoding[slot]
-                enc = jnp.concatenate(ent["parts"], axis=1)
-                self._finish_encdec(slot, ent, enc, now)
+            last = end >= t_enc
+            n = end - ent["off"] + (len(req.prompt) if last else 0)
+            with self._admit_span(req.rid, n=n):
+                t0 = time.perf_counter()
+                part = self._encode(
+                    self.params, jnp.asarray(req.frames[ent["off"]:end])[None])
+                ent["parts"].append(part)
+                ent["off"] = end
+                self.stats["prefill_s"] += time.perf_counter() - t0
+                if last:
+                    del self._encoding[slot]
+                    enc = jnp.concatenate(ent["parts"], axis=1)
+                    self._finish_encdec(slot, ent, enc, now)
 
     def _finish_encdec(self, slot: int, ent: dict, enc, now: float) -> None:
         """Complete an encdec admission: decoder-prompt prefill against the
@@ -867,8 +914,7 @@ class ContinuousBatchingEngine:
         comp = Completion(rid=req.rid, slot=slot, prompt_len=plen,
                           max_new_tokens=req.max_new_tokens,
                           admitted_s=ent["admit_s"], seq=self._admit_seq)
-        comp.ttft_s = (max(0.0, t1 - self._run_start - req.arrival_s)
-                       if self._run_start is not None else t1 - ent["t0"])
+        comp.ttft_s = max(0.0, t1 - self._offered_ns(req) * 1e-9)
         self.slot_owner[slot] = comp
         self.slot_req[slot] = req
         comp.tokens.append(tok)
@@ -939,7 +985,7 @@ class ContinuousBatchingEngine:
         self.pending.insert(0, Request(
             rid=comp.rid, prompt=tuple(req.prompt) + tuple(comp.tokens),
             max_new_tokens=max(1, remaining), arrival_s=0.0, resumed=True,
-            frames=req.frames))
+            frames=req.frames, queued_ns=time.perf_counter_ns()))
         self._release_slot(slot)
         self.stats["preempted"] += 1
 
@@ -1092,44 +1138,50 @@ class ContinuousBatchingEngine:
         retirement can occur in between).  Returns False when idle."""
         if now is None:
             now = 0.0
-        self._admit_arrived(now)
-        if self._encoding:
-            self._advance_encoding(now)
-        active = self.active_slots()
-        if not active:
-            return bool(self._encoding)
-        runahead = self._runahead([self.slot_owner[s] for s in active])
-        if self.paged:
-            runahead = self._ensure_pages(runahead, now)
-            active = self.active_slots()     # preemption may have shrunk it
+        spans = self.spans
+        with spans.span("sched.step"):
+            self._admit_arrived(now)
+            if self._encoding:
+                self._advance_encoding(now)
+            active = self.active_slots()
             if not active:
-                return bool(self.pending or self._swapped)
-        mask = np.zeros((self.n_slots,), bool)
-        mask[active] = True
+                return bool(self._encoding)
+            runahead = self._runahead([self.slot_owner[s] for s in active])
+            if self.paged:
+                with spans.span("sched.pages"):
+                    runahead = self._ensure_pages(runahead, now)
+                active = self.active_slots()  # preemption may have shrunk it
+                if not active:
+                    return bool(self.pending or self._swapped)
+            mask = np.zeros((self.n_slots,), bool)
+            mask[active] = True
 
-        mask_dev = jnp.asarray(mask)
-        toks_dev = jnp.asarray(self.next_tok, jnp.int32)
-        sampled = []
-        t0 = time.perf_counter()
-        for _ in range(runahead):
-            toks_dev, self.pool, self.key = self._step(
-                self.params, self.pool, toks_dev, self.key, mask_dev)
-            sampled.append(toks_dev)
-        # harvest host-side (np.stack, not jnp: a device stack would compile
-        # a fresh concatenate for every distinct run-ahead length)
-        jax.block_until_ready(sampled[-1])
-        harvested = np.stack([np.asarray(t) for t in sampled])
-        self.stats["decode_s"] += time.perf_counter() - t0
-        self.stats["decode_tokens"] += len(active) * runahead
-        self.stats["steps"] += runahead
+            mask_dev = jnp.asarray(mask)
+            toks_dev = jnp.asarray(self.next_tok, jnp.int32)
+            sampled = []
+            with spans.span("sched.decode", n=len(active), runahead=runahead):
+                t0 = time.perf_counter()
+                for _ in range(runahead):
+                    toks_dev, self.pool, self.key = self._step(
+                        self.params, self.pool, toks_dev, self.key, mask_dev)
+                    sampled.append(toks_dev)
+                # harvest host-side (np.stack, not jnp: a device stack would
+                # compile a fresh concatenate for every distinct run-ahead
+                # length)
+                jax.block_until_ready(sampled[-1])
+                harvested = np.stack([np.asarray(t) for t in sampled])
+                self.stats["decode_s"] += time.perf_counter() - t0
+            self.stats["decode_tokens"] += len(active) * runahead
+            self.stats["steps"] += runahead
 
-        for row in harvested:                        # [runahead, n_slots]
-            for slot in active:
-                self.slot_owner[slot].tokens.append(int(row[slot]))
-        for slot in active:
-            self.next_tok[slot] = self.slot_owner[slot].tokens[-1]
-            self._maybe_retire(slot, now)
-        return True
+            with spans.span("sched.retire"):
+                for row in harvested:                # [runahead, n_slots]
+                    for slot in active:
+                        self.slot_owner[slot].tokens.append(int(row[slot]))
+                for slot in active:
+                    self.next_tok[slot] = self.slot_owner[slot].tokens[-1]
+                    self._maybe_retire(slot, now)
+            return True
 
     # -- drive to completion -------------------------------------------------
     def run(self, requests=None, *, use_wall_clock: bool | None = None
