@@ -15,7 +15,16 @@ separate XLA stages:
     a scalar-prefetched page table (``pltpu.PrefetchScalarGridSpec``): the
     table is available before the kernel body runs, so each grid step's
     page DMAs are issued from table entries instead of materializing a
-    host-visible ``jnp.take`` gather of the whole slot in HBM.
+    host-visible ``jnp.take`` gather of the whole slot in HBM,
+  * the paged sweep is **bounded by each slot's length**: the page index
+    maps read the prefetched lengths and clamp every fetch to the slot's
+    last valid page, so a tile wholly past the length repeats the block
+    indices of the last valid tile (the TPU pipeline skips a fetch whose
+    block index is unchanged from the previous grid step) and its body
+    folds nothing (``pl.when``).  A slot reads ``ceil(length / ps)``
+    distinct pages, plus at most ``pages_per_tile - 1`` repeats of its
+    last page inside its last valid tile; a free slot (length 0) reads
+    only its table column 0 and folds nothing.
 
 Grid layout: ``(slots, Hkv, KV tiles)`` for the contiguous kernel and
 ``(slots, KV tiles)`` for the paged one, the KV sweep innermost either
@@ -53,7 +62,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.core.numerics import exp2_int, ext_exp
+from repro.core.numerics import MINUS_INF_N, exp2_int, ext_exp
 from repro.kernels.twopass_softmax import _interpret, _tpu_params
 
 NEG_INF = -jnp.inf
@@ -73,12 +82,29 @@ def _grid_spec(num_scalar_prefetch, grid, in_specs, out_specs):
         in_specs=in_specs, out_specs=out_specs)
 
 
+def _init_acc(o_ref, m_ref, n_ref):
+    """Set every head's (o, m_sum, n_sum) accumulator to the (m, n) monoid
+    zero (0, 0, ``MINUS_INF_N``): folding a tile into it is exact (its
+    rescale is 2^0 on the tile's side and a flush to 0 on the zero's), and
+    a slot that folds nothing normalizes to exact zeros."""
+    for h in range(o_ref.shape[1]):
+        o_ref[0, h] = jnp.zeros(o_ref.shape[2:], jnp.float32)
+        m_ref[0, h] = jnp.zeros(m_ref.shape[2:], jnp.float32)
+        n_ref[0, h] = jnp.full(n_ref.shape[2:], MINUS_INF_N, jnp.float32)
+
+
+def _normalize(o_ref, m_ref):
+    # max() guard: a free slot (length 0) has m_sum == 0 -> exact zeros
+    for h in range(o_ref.shape[1]):
+        o_ref[0, h] = o_ref[0, h] / jnp.maximum(m_ref[0, h], 1e-37)
+
+
 def _mn_fold_tile(o_ref, m_ref, n_ref, q, k, v, kpos, length, *,
-                  scale: float, window: int | None, j, last_j: int,
+                  scale: float, window: int | None,
                   k_scale=None, v_scale=None, h: int = 0):
-    """Score one KV tile, mask it, fold it into the running (o, m, n)
-    accumulator refs (head ``h`` of their block), and normalize on the
-    sweep's last step.
+    """Score one KV tile, mask it, and fold it into the running (o, m, n)
+    accumulator refs (head ``h`` of their block; :func:`_init_acc` sets
+    them before the sweep, :func:`_normalize` ends it).
 
     ``q``: (G, D) f32; ``k``/``v``: (BT, D)/(BT, Dv) f32; ``kpos``: int32
     (1, BT) logical cache positions of the tile's columns (2-D for Mosaic's
@@ -86,11 +112,11 @@ def _mn_fold_tile(o_ref, m_ref, n_ref, q, k, v, kpos, length, *,
     valid prefix (its own query sits at ``length - 1``, write-then-attend,
     so the validity prefix IS the causal mask and SWA is a lower bound off
     that query position).  A fully-masked tile contributes the exact
-    monoid zero (m=0, n=-inf); a fully-masked SLOT (length 0, a free pool
-    slot) ends with m_sum == 0 and the normalize guard returns exact
-    zeros, never NaN — matching the jnp reference forms bit-for-bit in
-    structure (the accumulation order within a tile differs, so parity is
-    allclose, not bitwise).
+    monoid zero (m=0, n=MINUS_INF_N); a fully-masked SLOT (length 0, a
+    free pool slot) ends with m_sum == 0 and the normalize guard returns
+    exact zeros, never NaN — matching the jnp reference forms bit-for-bit
+    in structure (the accumulation order within a tile differs, so parity
+    is allclose, not bitwise).
 
     ``k_scale``/``v_scale`` ((1, BT) f32) fuse int8 dequantization into
     the fold: ``k``/``v`` then hold raw int8 codes cast to f32 in-register
@@ -117,40 +143,35 @@ def _mn_fold_tile(o_ref, m_ref, n_ref, q, k, v, kpos, length, *,
     o_loc = jax.lax.dot_general(w, v, (((1,), (0,)), ((), ())),
                                 preferred_element_type=jnp.float32)
 
-    @pl.when(j == 0)
-    def _init():
-        o_ref[0, h] = o_loc
-        m_ref[0, h] = m_loc
-        n_ref[0, h] = n_loc
-
-    @pl.when(j > 0)
-    def _fold():
-        n_old = n_ref[0, h]
-        n_new = jnp.maximum(n_old, n_loc)
-        a_old = exp2_int(n_old - n_new)              # exact 2^k rescales
-        a_loc = exp2_int(n_loc - n_new)
-        o_ref[0, h] = o_ref[0, h] * a_old + o_loc * a_loc
-        m_ref[0, h] = m_ref[0, h] * a_old + m_loc * a_loc
-        n_ref[0, h] = n_new
-
-    @pl.when(j == last_j)
-    def _normalize():
-        # max() guard: a free slot (length 0) has m_sum == 0 -> exact zeros
-        o_ref[0, h] = o_ref[0, h] / jnp.maximum(m_ref[0, h], 1e-37)
+    n_old = n_ref[0, h]
+    n_new = jnp.maximum(n_old, n_loc)
+    a_old = exp2_int(n_old - n_new)                  # exact 2^k rescales
+    a_loc = exp2_int(n_loc - n_new)
+    o_ref[0, h] = o_ref[0, h] * a_old + o_loc * a_loc
+    m_ref[0, h] = m_ref[0, h] * a_old + m_loc * a_loc
+    n_ref[0, h] = n_new
 
 
 def _contig_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, n_ref, *,
                    scale: float, window: int | None, block_t: int, nt: int):
     s_idx = pl.program_id(0)
     j = pl.program_id(2)
+
+    @pl.when(j == 0)
+    def _init():
+        _init_acc(o_ref, m_ref, n_ref)
+
     kpos = (j * block_t
             + jax.lax.broadcasted_iota(jnp.int32, (1, block_t), 1))
     _mn_fold_tile(o_ref, m_ref, n_ref,
                   q_ref[0, 0].astype(jnp.float32),
                   k_ref[0, 0].astype(jnp.float32),
                   v_ref[0, 0].astype(jnp.float32),
-                  kpos, len_ref[s_idx], scale=scale, window=window,
-                  j=j, last_j=nt - 1)
+                  kpos, len_ref[s_idx], scale=scale, window=window)
+
+    @pl.when(j == nt - 1)
+    def _finish():
+        _normalize(o_ref, m_ref)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "window", "block_t"))
@@ -223,27 +244,42 @@ def _paged_kernel(pt_ref, len_ref, q_ref, *refs, scale: float,
 
     s_idx = pl.program_id(0)
     j = pl.program_id(1)
-    kpos = (j * (ppt * ps)
-            + jax.lax.broadcasted_iota(jnp.int32, (1, ppt * ps), 1))
-    for h in range(hkv):
-        # Each of the tile's ppt pages arrived via its own scalar-prefetch
-        # block fetch (non-contiguous in the arena); concatenated they
-        # form the contiguous logical window [j*ppt*ps, (j+1)*ppt*ps).  On
-        # the quantized path the astype is the whole dequant story: int8
-        # codes widen to f32 IN REGISTER, per tile — the arena itself is
-        # never copied to a full-precision buffer.
-        k = jnp.concatenate([r[0, :, h, :].astype(jnp.float32)
-                             for r in krefs], 0)
-        v = jnp.concatenate([r[0, :, h, :].astype(jnp.float32)
-                             for r in vrefs], 0)
-        ks = vs = None
-        if quant:
-            ks = jnp.concatenate([srow(r, h) for r in ksrefs], 1)
-            vs = jnp.concatenate([srow(r, h) for r in vsrefs], 1)
-        _mn_fold_tile(o_ref, m_ref, n_ref, q_ref[0, h].astype(jnp.float32),
-                      k, v, kpos, len_ref[s_idx], scale=scale,
-                      window=window, j=j, last_j=nt - 1, k_scale=ks,
-                      v_scale=vs, h=h)
+    length = len_ref[s_idx]
+
+    @pl.when(j == 0)
+    def _init():
+        _init_acc(o_ref, m_ref, n_ref)
+
+    # A tile wholly past the slot's length folds nothing (and its index
+    # maps repeated the last valid tile's pages, so nothing was fetched).
+    @pl.when(j * (ppt * ps) < length)
+    def _fold():
+        kpos = (j * (ppt * ps)
+                + jax.lax.broadcasted_iota(jnp.int32, (1, ppt * ps), 1))
+        for h in range(hkv):
+            # Each of the tile's ppt pages arrived via its own
+            # scalar-prefetch block fetch (non-contiguous in the arena);
+            # concatenated they form the contiguous logical window
+            # [j*ppt*ps, (j+1)*ppt*ps).  On the quantized path the astype
+            # is the whole dequant story: int8 codes widen to f32 IN
+            # REGISTER, per tile — the arena itself is never copied to a
+            # full-precision buffer.
+            k = jnp.concatenate([r[0, :, h, :].astype(jnp.float32)
+                                 for r in krefs], 0)
+            v = jnp.concatenate([r[0, :, h, :].astype(jnp.float32)
+                                 for r in vrefs], 0)
+            ks = vs = None
+            if quant:
+                ks = jnp.concatenate([srow(r, h) for r in ksrefs], 1)
+                vs = jnp.concatenate([srow(r, h) for r in vsrefs], 1)
+            _mn_fold_tile(o_ref, m_ref, n_ref,
+                          q_ref[0, h].astype(jnp.float32), k, v, kpos,
+                          length, scale=scale, window=window, k_scale=ks,
+                          v_scale=vs, h=h)
+
+    @pl.when(j == nt - 1)
+    def _finish():
+        _normalize(o_ref, m_ref)
 
 
 @functools.partial(jax.jit,
@@ -264,9 +300,10 @@ def decode_attention_paged_pallas(q: jax.Array, k_pages: jax.Array,
     per-page BlockSpec index maps read ``page_table`` directly, so each
     grid step DMAs ``pages_per_tile`` non-contiguous arena pages (all
     their KV heads) into VMEM and attends them, head by head, as one
-    contiguous logical window.  Table entries backing no valid position
-    (free slots, pages past ``lengths``, the pad below) may point
-    anywhere in the arena — the length mask makes their content
+    contiguous logical window.  The sweep stops at each slot's last
+    valid page (module docstring): table entries past it are never
+    fetched, and those inside the last page and a free slot's column 0
+    may point anywhere in the arena — the length mask makes their content
     invisible.  Returns [S, Hkv, G, Dv] in q.dtype.
 
     int8 arenas pass ``k_scale``/``v_scale`` fp32 sidecars (``[P, ps]``
@@ -284,17 +321,22 @@ def decode_attention_paged_pallas(q: jax.Array, k_pages: jax.Array,
     pmax = page_table.shape[1]
     quant = k_scale is not None
     ppt = max(1, min(pages_per_tile, pmax, MAX_PAGES_PER_TILE))
-    ppad = pl.cdiv(pmax, ppt) * ppt
-    if ppad != pmax:
-        # pad the table with arena page 0 (the pool's trash page; any
-        # in-bounds id works — padded logical positions are masked)
-        page_table = jnp.pad(page_table, ((0, 0), (0, ppad - pmax)))
-    nt = ppad // ppt
+    nt = pl.cdiv(pmax, ppt)
+
+    def page_index(si, j, tab, ln, i):
+        # The sweep stops at the slot's last page holding a valid position
+        # (table column 0 for a free slot): a tile past it repeats the
+        # block indices of the last valid tile, which the pipeline does
+        # not fetch again, and page slots past it inside that tile clamp
+        # to it.  Columns never pass pmax - 1, so the table needs no pad.
+        last = jnp.clip(jax.lax.div(ln[si] + ps - 1, ps) - 1, 0, pmax - 1)
+        col = jnp.minimum(jax.lax.div(last, ppt), j) * ppt + i
+        return tab[si, jnp.minimum(col, last)]
 
     def page_spec(i, block):
         return pl.BlockSpec(
             block,
-            lambda si, j, tab, ln, i=i: (tab[si, j * ppt + i],)
+            lambda si, j, tab, ln, i=i: (page_index(si, j, tab, ln, i),)
             + (0,) * (len(block) - 1))
 
     kernel = functools.partial(_paged_kernel, scale=scale, window=window,

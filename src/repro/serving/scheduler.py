@@ -423,7 +423,8 @@ class ContinuousBatchingEngine:
                           decode_s=0.0, steps=0, admitted=0, preempted=0,
                           peak_pages=0, prefix_hits=0, prefix_tokens_reused=0,
                           cow_copies=0, prefix_evictions=0, demoted=0,
-                          prefetched=0)
+                          prefetched=0, decode_pages_read=0,
+                          decode_pages_table=0)
 
     # -- mesh plumbing -------------------------------------------------------
     def _with_mesh(self, fn):
@@ -630,6 +631,13 @@ class ContinuousBatchingEngine:
     # -- paged bookkeeping ---------------------------------------------------
     def _pages_for(self, tokens: int) -> int:
         return -(-int(tokens) // self.page_size)
+
+    def _dev_len(self, slot: int) -> int:
+        """Positions the slot holds on the device before its next decode
+        step writes one more (write-then-attend: that step attends over
+        ``_dev_len + 1``)."""
+        comp = self.slot_owner[slot]
+        return comp.prompt_len + len(comp.tokens) - 1
 
     def _page_row(self, slot: int) -> np.ndarray:
         # np, not jnp: jitted callees take host arrays through the C++
@@ -1023,7 +1031,7 @@ class ContinuousBatchingEngine:
             return False
         self._swapped[comp.rid] = dict(
             comp=comp, req=self.slot_req[slot],
-            length=comp.prompt_len + len(comp.tokens) - 1,
+            length=self._dev_len(slot),
             next_tok=int(self.next_tok[slot]))
         self._release_slot(slot)
         self.stats["demoted"] += 1
@@ -1068,9 +1076,7 @@ class ContinuousBatchingEngine:
                 return 0
 
             def extra(slot: int, h: int) -> int:
-                comp = self.slot_owner[slot]
-                dev_len = comp.prompt_len + len(comp.tokens) - 1
-                target = min(dev_len + h, self.max_len)
+                target = min(self._dev_len(slot) + h, self.max_len)
                 return max(0,
                            self._pages_for(target) -
                            len(self.slot_pages[slot]))
@@ -1173,6 +1179,15 @@ class ContinuousBatchingEngine:
                 self.stats["decode_s"] += time.perf_counter() - t0
             self.stats["decode_tokens"] += len(active) * runahead
             self.stats["steps"] += runahead
+            if self.paged:
+                # pages the paged decode sweep reads (each active slot's
+                # ceil(length / page_size) at every step of the burst)
+                # against the whole page table it could read
+                self.stats["decode_pages_read"] += sum(
+                    self._pages_for(self._dev_len(s) + r)
+                    for s in active for r in range(1, runahead + 1))
+                self.stats["decode_pages_table"] += (
+                    self.n_slots * self.pages_per_slot * runahead)
 
             with spans.span("sched.retire"):
                 for row in harvested:                # [runahead, n_slots]
@@ -1309,6 +1324,8 @@ class ContinuousBatchingEngine:
                        pages=self.allocator.usable_pages,
                        peak_pages=st["peak_pages"],
                        preempted=st["preempted"],
+                       decode_pages_read=st["decode_pages_read"],
+                       decode_pages_table=st["decode_pages_table"],
                        prefix_cache=self.prefix_cache is not None)
             if self.page_dtype is not None:
                 out.update(page_dtype=self.page_dtype,

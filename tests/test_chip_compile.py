@@ -93,6 +93,25 @@ def test_paged_decode_compiles(tpu, head_dim, page_dtype):
     _compile(decode, *args)
 
 
+def test_paged_decode_int8_compiles_at_eight_pages_per_tile(tpu):
+    """The int8 arena with "page" scales at the benchmark cells' table:
+    24 pages of 128 per slot, hd 120, swept at 8 pages per tile, with the
+    length-clamped page and scale index maps."""
+    from repro.kernels import decode_attention as da
+
+    slots, hkv, g, ps, pages, pmax, hd = 64, 8, 4, 128, 320, 24, 120
+    arena = _sds(tpu, (pages, ps, hkv, hd), jnp.int8)
+    scales = _sds(tpu, (pages, ps), jnp.float32)
+    _compile(lambda q, k, v, table, lengths, ks, vs:
+             da.decode_attention_paged_pallas(
+                 q, k, v, table, lengths, ks, vs, scale=hd ** -0.5,
+                 pages_per_tile=8),
+             _sds(tpu, (slots, hkv, g, hd), jnp.bfloat16), arena, arena,
+             _sds(tpu, (slots, pmax), jnp.int32), _sds(tpu, (slots,),
+                                                      jnp.int32),
+             scales, scales)
+
+
 @pytest.mark.parametrize("head_dim", [120, 128])
 def test_flash_attention_fwd_bwd_compile(tpu, head_dim):
     """Training attention at a 4k causal sequence: the stats-saving

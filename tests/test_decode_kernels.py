@@ -1,8 +1,9 @@
 """Pallas decode-attention kernel tests: interpret-mode parity with the jnp
 (m, n) reference forms (contiguous + paged, lengths incl. zero/full, SWA
-window, shuffled/aliased page tables), SoftmaxPolicy.use_kernels dispatch,
-and a ragged end-to-end serving run asserting identical tokens with the
-kernels on and off."""
+window, shuffled/aliased page tables), the paged sweep bounded by each
+slot's length (NaN pages past it stay unread), SoftmaxPolicy.use_kernels
+dispatch, and a ragged end-to-end serving run asserting identical tokens
+with the kernels on and off."""
 
 import jax
 import jax.numpy as jnp
@@ -143,8 +144,8 @@ class TestPallasPagedParity:
         np.testing.assert_array_equal(np.asarray(got[3]), 0.0)
 
     def test_table_width_padded_to_tile(self):
-        # pmax=6 with pages_per_tile=4 pads the table to 8 entries; the
-        # pad points at the trash page and must not contribute.
+        # pmax=6 with pages_per_tile=4: the second tile's last two page
+        # slots lie past the table and clamp to the slot's last page.
         got = da.decode_attention_paged_pallas(
             self.q, self.kp, self.vp, self.pt, self.lengths,
             scale=self.d ** -0.5, pages_per_tile=4)
@@ -163,6 +164,41 @@ class TestPallasPagedParity:
                                     use_kernel=False)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# The paged sweep stops at each slot's last valid page: tiles past it are
+# neither fetched nor folded, so what lies there cannot reach the output.
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("poisoned", [False, True])
+@pytest.mark.parametrize("ppt", [1, 2, 4, 8])
+def test_paged_sweep_bounded_by_length(ppt, poisoned):
+    # one slot per boundary: free, one position, a page's edges, a tile's
+    # edges, the whole table (12 pages: ppt 8 leaves a part tile)
+    ps, pmax = 8, 12
+    lengths = [0, 1, ps - 1, ps, ps + 1, ppt * ps, ppt * ps + 1, pmax * ps]
+    s, h, g, d = len(lengths), 2, 3, 16
+    ks = jax.random.split(jax.random.fold_in(KEY, ppt), 3)
+    q = jax.random.normal(ks[0], (s, h, g, d))
+    k = jax.random.normal(ks[1], (s, h, pmax * ps, d))
+    v = jax.random.normal(ks[2], (s, h, pmax * ps, d))
+    lengths = jnp.asarray(lengths, jnp.int32)
+    kp, vp, pt = _paged_copy(k, v, pmax, ps)
+    if poisoned:
+        # NaN in every page that backs no valid position (page 0, the
+        # trash page, stays finite): a page read past a length would
+        # reach the output as 0 * NaN
+        tab = np.asarray(pt)
+        live = {int(tab[i, p]) for i, n in enumerate(np.asarray(lengths))
+                for p in range(-(-int(n) // ps))}
+        dead = np.array(sorted(set(range(1, kp.shape[0])) - live))
+        kp, vp = kp.at[dead].set(jnp.nan), vp.at[dead].set(jnp.nan)
+    want = ops.decode_attention(q, k, v, lengths, use_kernel=False)
+    got = np.asarray(da.decode_attention_paged_pallas(
+        q, kp, vp, pt, lengths, scale=d ** -0.5, pages_per_tile=ppt))
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, np.asarray(want), atol=1e-5)
+    np.testing.assert_array_equal(got[0], 0.0)           # free slot
 
 
 # ---------------------------------------------------------------------------

@@ -1,10 +1,11 @@
 """Quantized int8 KV pages + host-RAM swap tier tests: symmetric-absmax
 round-trip bounds per scale granularity, fused-dequant paged-decode parity
-(jnp and Pallas paths, shuffled and aliased page tables), equal-byte-budget
-capacity math (int8 admits >= 1.8x the page tokens), the bf16 default path
-staying byte-for-byte untouched, bit-exact demote/promote through the
-swap tier, the shared-page (refcount > 1) demote refusal, and the
-swap-vs-preempt choice under page pressure."""
+(jnp and Pallas paths, shuffled and aliased page tables, poisoned pages
+past the lengths left unread), equal-byte-budget capacity math (int8
+admits >= 1.8x the page tokens), the bf16 default path staying
+byte-for-byte untouched, bit-exact demote/promote through the swap tier,
+the shared-page (refcount > 1) demote refusal, and the swap-vs-preempt
+choice under page pressure."""
 
 import numpy as np
 import pytest
@@ -12,6 +13,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import decode_attention as da
 from repro.kernels import ops
 from repro.models import build_model
 from repro.serving import kv_cache
@@ -137,6 +139,34 @@ class TestFusedDequantOp:
                                          use_kernel=True)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-5, atol=2e-5)
+
+
+    @pytest.mark.parametrize("granularity", ["page", "page_head"])
+    @pytest.mark.parametrize("ppt", [1, 4])
+    def test_pages_past_length_never_read(self, granularity, ppt):
+        # every page backing no valid position gets saturated codes and
+        # NaN scales: a page read past a length would reach the output
+        # through 0 * NaN in the fused dequant
+        kq, ksc, kdeq = _quant_arena(self.key, self.pages, self.ps, self.h,
+                                     self.d, granularity)
+        vq, vsc, vdeq = _quant_arena(jax.random.fold_in(self.key, 1),
+                                     self.pages, self.ps, self.h, self.d,
+                                     granularity)
+        tab = np.asarray(self.pt)
+        live = {int(tab[i, p]) for i, n in enumerate(np.asarray(self.lengths))
+                for p in range(-(-int(n) // self.ps))}
+        dead = np.array(sorted(set(range(1, self.pages)) - live))
+        kq, vq = kq.at[dead].set(127), vq.at[dead].set(127)
+        ksc, vsc = ksc.at[dead].set(jnp.nan), vsc.at[dead].set(jnp.nan)
+        want = ops.decode_attention_paged(self.q, kdeq, vdeq, self.pt,
+                                          self.lengths, use_kernel=False)
+        got = np.asarray(da.decode_attention_paged_pallas(
+            self.q, kq, vq, self.pt, self.lengths, ksc, vsc,
+            scale=self.d ** -0.5, pages_per_tile=ppt))
+        assert np.isfinite(got).all()
+        np.testing.assert_allclose(got, np.asarray(want), rtol=2e-5,
+                                   atol=2e-5)
+        np.testing.assert_array_equal(got[3], 0.0)       # free slot
 
 
 # ---------------------------------------------------------------------------

@@ -249,6 +249,44 @@ class TestPagedScheduler:
                                   max_new_tokens=20) for i in range(2)])
         assert [c.tokens for c in comps] == [c.tokens for c in rcomps]
 
+    def test_decode_page_counters(self):
+        """``decode_pages_read`` sums ceil(length / page_size) over the
+        active slots at every decode step, against the whole table in
+        ``decode_pages_table``; both reset with the other stats."""
+        eng = ContinuousBatchingEngine(self.m, self.params, slots=3,
+                                       max_len=48, page_size=8,
+                                       temperature=0.0, seed=6)
+        ps = eng.page_size
+        device_pages = []
+        step = eng._step
+
+        def spy(params, pool, toks, key, mask):
+            out = step(params, pool, toks, key, mask)
+            lens = np.asarray(out[1]["lengths"])[np.asarray(mask)]
+            device_pages.append(sum(-(-int(n) // ps) for n in lens))
+            return out
+
+        eng._step = spy
+        rng = np.random.default_rng(5)
+        reqs = [Request(rid=i,
+                        prompt=tuple(rng.integers(1, self.m.cfg.vocab,
+                                                  int(rng.integers(3, 20)))),
+                        max_new_tokens=4 + 3 * i) for i in range(5)]
+        comps = eng.run(reqs)
+        # the step that samples tokens[k] (k >= 1) attends over
+        # prompt_len + k positions
+        want = sum(-(-(c.prompt_len + k) // ps)
+                   for c in comps for k in range(1, len(c.tokens)))
+        st = eng.stats
+        assert st["decode_pages_read"] == want == sum(device_pages) > 0
+        assert st["decode_pages_table"] == (eng.n_slots * eng.pages_per_slot
+                                            * st["steps"])
+        assert st["decode_pages_read"] <= st["decode_pages_table"]
+        assert eng.throughput()["decode_pages_read"] == want
+        eng.reset_stats()
+        assert eng.stats["decode_pages_read"] == 0
+        assert eng.stats["decode_pages_table"] == 0
+
     def test_bucketed_prefill_bounds_compiles(self):
         eng = ContinuousBatchingEngine(self.m, self.params, slots=2,
                                        max_len=64, page_size=16,
