@@ -16,29 +16,29 @@ separate XLA stages:
     table is available before the kernel body runs, so each grid step's
     page DMAs are issued from table entries instead of materializing a
     host-visible ``jnp.take`` gather of the whole slot in HBM,
-  * the paged sweep is **bounded by each slot's length**: the page index
-    maps read the prefetched lengths and clamp every fetch to the slot's
-    last valid page, so a tile wholly past the length repeats the block
-    indices of the last valid tile (the TPU pipeline skips a fetch whose
-    block index is unchanged from the previous grid step) and its body
-    folds nothing (``pl.when``).  A slot reads ``ceil(length / ps)``
-    distinct pages, plus at most ``pages_per_tile - 1`` repeats of its
-    last page inside its last valid tile; a free slot (length 0) reads
-    only its table column 0 and folds nothing.
+  * the paged sweep is **bounded by each slot's length**: its grid is one
+    step per KV tile that holds a valid position — a work list of
+    (slot, tile) pairs, built on the device from the lengths and
+    scalar-prefetched, whose length is the grid's (dynamic) size — and
+    the page index maps clamp every fetch to the slot's last valid page.
+    A slot reads ``ceil(length / ps)`` distinct pages, plus at most
+    ``pages_per_tile - 1`` repeats of its last page inside its last tile;
+    a free slot (length 0) has no grid step and its output is zeros.
 
-Grid layout: ``(slots, Hkv, KV tiles)`` for the contiguous kernel and
-``(slots, KV tiles)`` for the paged one, the KV sweep innermost either
-way, so the per-(slot, head) accumulators ``(o, m_sum, n_sum)`` live in
-VMEM across the whole sweep (same revisited-output pattern as
-``flash_attention``).  A paged grid step fetches every KV head of its
-pages: the arena is ``[P, ps, Hkv, D]``, and the TPU lowering only takes
-blocks whose last two dims are (8, 128)-aligned or whole, so a one-head
-``(ps, 1, D)`` slice of a page cannot be a block.  The head loop runs
-inside the kernel instead.  One grid row per slot: the slot axis never
-tiles — the tunable dims are the KV tile length (``block_t``, contiguous)
-and the page count per tile (``pages_per_tile``, paged), swept by
-``repro.kernels.autotune`` through the ``decode_attention`` /
-``decode_attention_paged`` registry ops.
+Grid layout: ``(slots, Hkv, KV tiles)`` for the contiguous kernel, the
+KV sweep innermost, and the ``(slot, tile)`` work list for the paged one,
+slot-major, so the per-(slot, head) accumulators ``(o, m_sum, n_sum)``
+live in VMEM across a slot's sweep (same revisited-output pattern as
+``flash_attention``) and the pipeline prefetches the next tile's pages,
+the next slot's included, while the current one folds.  A paged grid
+step fetches every KV head of its pages: the arena is ``[P, ps, Hkv, D]``,
+and the TPU lowering only takes blocks whose last two dims are
+(8, 128)-aligned or whole, so a one-head ``(ps, 1, D)`` slice of a page
+cannot be a block.  The head loop runs inside the kernel instead.  The
+slot axis never tiles — the tunable dims are the KV tile length
+(``block_t``, contiguous) and the page count per tile
+(``pages_per_tile``, paged), swept by ``repro.kernels.autotune`` through
+the ``decode_attention`` / ``decode_attention_paged`` registry ops.
 
 Dispatch: ``ops.decode_attention`` / ``ops.decode_attention_paged`` route
 here when the :class:`SoftmaxPolicy` says ``use_kernels`` (interpret mode
@@ -226,8 +226,8 @@ def decode_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
     return o.astype(q.dtype)
 
 
-def _paged_kernel(pt_ref, len_ref, q_ref, *refs, scale: float,
-                  window: int | None, ps: int, ppt: int, nt: int,
+def _paged_kernel(pt_ref, len_ref, ws_ref, wt_ref, q_ref, *refs,
+                  scale: float, window: int | None, ps: int, ppt: int,
                   hkv: int, quant: bool = False):
     krefs, vrefs = refs[:ppt], refs[ppt:2 * ppt]
     if quant:
@@ -242,16 +242,15 @@ def _paged_kernel(pt_ref, len_ref, q_ref, *refs, scale: float,
     def srow(r, h):                          # -> (1, ps) per-column scales
         return r[0] if r.shape[1] == 1 else r[0, :, h][None, :]
 
-    s_idx = pl.program_id(0)
-    j = pl.program_id(1)
-    length = len_ref[s_idx]
+    w = pl.program_id(0)
+    j = wt_ref[w]                            # tile of slot ws_ref[w]
+    length = len_ref[ws_ref[w]]
 
     @pl.when(j == 0)
     def _init():
         _init_acc(o_ref, m_ref, n_ref)
 
-    # A tile wholly past the slot's length folds nothing (and its index
-    # maps repeated the last valid tile's pages, so nothing was fetched).
+    # Only an all-free pool's one grid step holds no valid position.
     @pl.when(j * (ppt * ps) < length)
     def _fold():
         kpos = (j * (ppt * ps)
@@ -277,7 +276,7 @@ def _paged_kernel(pt_ref, len_ref, q_ref, *refs, scale: float,
                           length, scale=scale, window=window, k_scale=ks,
                           v_scale=vs, h=h)
 
-    @pl.when(j == nt - 1)
+    @pl.when((j + 1) * (ppt * ps) >= length)
     def _finish():
         _normalize(o_ref, m_ref)
 
@@ -296,15 +295,16 @@ def decode_attention_paged_pallas(q: jax.Array, k_pages: jax.Array,
 
     q: [S, Hkv, G, D]; k_pages/v_pages: [P, ps, Hkv, D|Dv] page arenas
     (``kv_cache.init_paged_pool`` layout); page_table: [S, Pmax] int32;
-    lengths: [S] int32.  Both int32 operands are scalar-prefetched: the
-    per-page BlockSpec index maps read ``page_table`` directly, so each
+    lengths: [S] int32.  Both int32 operands are scalar-prefetched, with
+    the work list built from ``lengths``: the per-page BlockSpec index
+    maps read ``page_table`` directly, so each
     grid step DMAs ``pages_per_tile`` non-contiguous arena pages (all
     their KV heads) into VMEM and attends them, head by head, as one
-    contiguous logical window.  The sweep stops at each slot's last
-    valid page (module docstring): table entries past it are never
-    fetched, and those inside the last page and a free slot's column 0
-    may point anywhere in the arena — the length mask makes their content
-    invisible.  Returns [S, Hkv, G, Dv] in q.dtype.
+    contiguous logical window.  The grid holds only the tiles below each
+    slot's length (module docstring): table entries past a slot's last
+    valid page are never fetched, and those inside that page and a free
+    slot's row may point anywhere in the arena — the length mask makes
+    their content invisible.  Returns [S, Hkv, G, Dv] in q.dtype.
 
     int8 arenas pass ``k_scale``/``v_scale`` fp32 sidecars (``[P, ps]``
     "page" granularity or ``[P, ps, Hkv]`` "page_head"): each page's scale
@@ -321,26 +321,38 @@ def decode_attention_paged_pallas(q: jax.Array, k_pages: jax.Array,
     pmax = page_table.shape[1]
     quant = k_scale is not None
     ppt = max(1, min(pages_per_tile, pmax, MAX_PAGES_PER_TILE))
+    lengths = lengths.astype(jnp.int32)
+    # The work list: slot-major (slot, tile) pairs of the tiles that hold
+    # a valid position; entries past the count are never visited.  An
+    # all-free pool still runs one (folding nothing) step.
     nt = pl.cdiv(pmax, ppt)
+    tiles = jnp.minimum(jax.lax.div(lengths + ppt * ps - 1, ppt * ps), nt)
+    ends = jnp.cumsum(tiles)
+    work = jnp.arange(s * nt, dtype=jnp.int32)
+    wslot = jnp.minimum(jnp.sum(ends[None, :] <= work[:, None], axis=1,
+                                dtype=jnp.int32), s - 1)
+    wtile = work - (ends - tiles)[wslot]
+    n_work = jnp.maximum(ends[-1], 1)
 
-    def page_index(si, j, tab, ln, i):
-        # The sweep stops at the slot's last page holding a valid position
-        # (table column 0 for a free slot): a tile past it repeats the
-        # block indices of the last valid tile, which the pipeline does
-        # not fetch again, and page slots past it inside that tile clamp
-        # to it.  Columns never pass pmax - 1, so the table needs no pad.
+    def page_index(w, tab, ln, ws, wt, i):
+        # Page slots past the slot's last valid page clamp to it, so the
+        # table is never read past a length (or past pmax - 1).
+        si = ws[w]
         last = jnp.clip(jax.lax.div(ln[si] + ps - 1, ps) - 1, 0, pmax - 1)
-        col = jnp.minimum(jax.lax.div(last, ppt), j) * ppt + i
-        return tab[si, jnp.minimum(col, last)]
+        return tab[si, jnp.minimum(wt[w] * ppt + i, last)]
 
     def page_spec(i, block):
         return pl.BlockSpec(
             block,
-            lambda si, j, tab, ln, i=i: (page_index(si, j, tab, ln, i),)
-            + (0,) * (len(block) - 1))
+            lambda w, tab, ln, ws, wt, i=i:
+            (page_index(w, tab, ln, ws, wt, i),) + (0,) * (len(block) - 1))
+
+    def slot_spec(block):
+        return pl.BlockSpec(block, lambda w, tab, ln, ws, wt:
+                            (ws[w],) + (0,) * (len(block) - 1))
 
     kernel = functools.partial(_paged_kernel, scale=scale, window=window,
-                               ps=ps, ppt=ppt, nt=nt, hkv=hkv, quant=quant)
+                               ps=ps, ppt=ppt, hkv=hkv, quant=quant)
     scale_specs, scale_args = [], ()
     if quant:
         if k_scale.ndim == 2:                        # [P, ps] "page"
@@ -349,23 +361,15 @@ def decode_attention_paged_pallas(q: jax.Array, k_pages: jax.Array,
         blk = (1,) + k_scale.shape[1:]
         scale_specs = [page_spec(i, blk) for i in range(ppt)] * 2
         scale_args = (*([k_scale] * ppt), *([v_scale] * ppt))
-    head_spec = pl.BlockSpec((1, hkv, g, d),
-                             lambda si, j, tab, ln: (si, 0, 0, 0))
     grid_spec = _grid_spec(
-        2, (s, nt),
+        4, (n_work,),
         in_specs=(
-            [head_spec]
+            [slot_spec((1, hkv, g, d))]
             + [page_spec(i, (1, ps, hkv, d)) for i in range(ppt)]
             + [page_spec(i, (1, ps, hkv, dv)) for i in range(ppt)]
             + scale_specs),
-        out_specs=[
-            pl.BlockSpec((1, hkv, g, dv),
-                         lambda si, j, tab, ln: (si, 0, 0, 0)),
-            pl.BlockSpec((1, hkv, g, 1),
-                         lambda si, j, tab, ln: (si, 0, 0, 0)),
-            pl.BlockSpec((1, hkv, g, 1),
-                         lambda si, j, tab, ln: (si, 0, 0, 0)),
-        ])
+        out_specs=[slot_spec((1, hkv, g, dv)), slot_spec((1, hkv, g, 1)),
+                   slot_spec((1, hkv, g, 1))])
     o, _, _ = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -375,7 +379,9 @@ def decode_attention_paged_pallas(q: jax.Array, k_pages: jax.Array,
             jax.ShapeDtypeStruct((s, hkv, g, 1), jnp.float32),
         ],
         interpret=_interpret(),
-        **_tpu_params(("parallel", "arbitrary")),
-    )(page_table.astype(jnp.int32), lengths.astype(jnp.int32),
+        **_tpu_params(("arbitrary",)),
+    )(page_table.astype(jnp.int32), lengths, wslot, wtile,
       q, *([k_pages] * ppt), *([v_pages] * ppt), *scale_args)
+    # a free slot has no grid step: its output block was never written
+    o = jnp.where((lengths > 0)[:, None, None, None], o, 0.0)
     return o.astype(q.dtype)

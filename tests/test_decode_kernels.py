@@ -199,6 +199,10 @@ def test_paged_sweep_bounded_by_length(ppt, poisoned):
     assert np.isfinite(got).all()
     np.testing.assert_allclose(got, np.asarray(want), atol=1e-5)
     np.testing.assert_array_equal(got[0], 0.0)           # free slot
+    idle = da.decode_attention_paged_pallas(             # all slots free
+        q, kp, vp, pt, jnp.zeros_like(lengths), scale=d ** -0.5,
+        pages_per_tile=ppt)
+    np.testing.assert_array_equal(np.asarray(idle), 0.0)
 
 
 # ---------------------------------------------------------------------------
