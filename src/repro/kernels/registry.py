@@ -259,7 +259,12 @@ def candidate_blocks(
 # Registered ops.  ``fn`` is filled in lazily by ops.py (kernels import this
 # module, not vice versa, so specs are declared here dependency-free).
 # ---------------------------------------------------------------------------
-register(KernelSpec(name="softmax"))
+# softmax: blocks at most 2048 columns wide, tuned or not.  A wider row
+# streams its columns (pass 1 folds them into (m, n)); a 2304- or
+# 3072-column block at 256 rows asks more than v5e's 16 MiB of scoped
+# VMEM (prefill scores past the 2048 bucket).
+register(KernelSpec(name="softmax", full_col_threshold=2048,
+                    tune_col_cap=2048))
 register(KernelSpec(name="logsumexp"))
 # fused CE: the former _xent_blocks capped block_v at 2048 unconditionally
 register(KernelSpec(name="xent", full_col_threshold=2048))

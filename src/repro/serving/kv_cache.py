@@ -36,6 +36,8 @@ row nothing ever reads validly.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -273,7 +275,7 @@ def init_paged_pool(cfg: ModelConfig, slots: int, max_len: int, tp: int = 1,
     ``mesh`` (a ('data', 'model') serving mesh) lays the pool out sharded
     per :func:`repro.distributed.sharding.pool_specs`: arena KV-head axis
     over ``model``, page table / lengths replicated (see
-    :func:`shard_pool`).
+    :func:`shard_pool`).  Each device makes only its own shard.
     """
     if not supports_paging(cfg):
         raise ValueError(f"family {cfg.family!r} has no pageable cache")
@@ -296,6 +298,18 @@ def init_paged_pool(cfg: ModelConfig, slots: int, max_len: int, tp: int = 1,
         n_xtab = pages_per_slot(cross_len or max_len, ps)
     if pages is None:
         pages = 1 + slots * (n_tab + n_xtab)
+    if mesh is not None:
+        # a whole arena made on one device first need not fit beside
+        # that device's share of the weights
+        from repro.distributed import sharding as _sh  # lazy import
+
+        make = functools.partial(
+            init_paged_pool, cfg, slots, max_len, tp, page_size=page_size,
+            pages=pages, page_dtype=page_dtype,
+            scale_granularity=scale_granularity, cross_len=cross_len)
+        shapes = jax.eval_shape(make)
+        return jax.jit(make, out_shardings=_sh.named(
+            _sh.pool_specs(shapes, cfg, mesh), mesh))()
     dt = cache_dtype(cfg)
     hd = cfg.resolved_head_dim()
     ls = cfg.n_layers
@@ -327,7 +341,7 @@ def init_paged_pool(cfg: ModelConfig, slots: int, max_len: int, tp: int = 1,
     if cfg.family == "encdec":
         pool["cross_table"] = jnp.zeros((slots, n_xtab), jnp.int32)
         pool["cross_lengths"] = jnp.zeros((slots,), jnp.int32)
-    return shard_pool(pool, cfg, mesh) if mesh is not None else pool
+    return pool
 
 
 def shard_pool(pool: dict, cfg: ModelConfig, mesh) -> dict:

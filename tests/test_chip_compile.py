@@ -147,3 +147,14 @@ def test_lmhead_xent_compiles_at_d3840(tpu, kernel):
         args += [_sds(tpu, (pt,), jnp.float32)]
     fn = getattr(xent, f"lmhead_xent_{kernel}_2d")
     _compile(lambda *a: fn(*a, block_t=bt, block_v=bv, v_len=v), *args)
+
+
+@pytest.mark.parametrize("cols", [2304, 3072])
+def test_softmax_compiles_past_the_2048_bucket(tpu, cols):
+    """Prefill attention scores of a prompt past the 2048 bucket (a
+    request preempted and readmitted with its tokens), f32[8192, cols] as
+    the chip saw them: at the blocks ``ops`` resolves (at most 2048
+    columns, streamed) the kernel fits the 16 MiB of scoped VMEM."""
+    from repro.kernels import ops
+
+    _compile(ops.softmax, _sds(tpu, (8192, cols), jnp.float32))

@@ -45,6 +45,22 @@ class TestSoftmaxKernels:
             np.asarray(got, np.float32), np.asarray(want, np.float32),
             **_tol(dtype))
 
+    @pytest.mark.parametrize("cols", [2304, 3072])
+    def test_prefill_width_past_2048_streams_columns(self, cols):
+        """Prefill scores past the 2048 bucket: the resolved block is at
+        most 2048 columns wide (what fits v5e's scoped VMEM), so the row
+        streams over column blocks, and the result is still the softmax.
+        The causal mask's -inf tail is kept."""
+        from repro.kernels import registry
+
+        assert registry.block_shapes("softmax", 40, cols)[1] <= 2048
+        x = jax.random.normal(KEY, (40, cols)) * 8
+        x = jnp.where(jnp.arange(cols) < cols - 100 - jnp.arange(40)[:, None],
+                      x, -jnp.inf)
+        np.testing.assert_allclose(np.asarray(ops.softmax(x)),
+                                   np.asarray(jax.nn.softmax(x, axis=-1)),
+                                   atol=5e-6)
+
     @pytest.mark.parametrize("algo", ALGOS)
     def test_block_shape_sweep(self, algo):
         """Meta-parameter sweep (the paper's auto-tuning axis): results must

@@ -201,13 +201,19 @@ class TestAutotuneCache:
         assert on.resolve_blocks("softmax", 16, 256) == (16, 128)
         assert off.resolve_blocks("softmax", 16, 256) == \
             registry.get_spec("softmax").heuristic_blocks(16, 256)
-        # bucket neighbor with fewer cols (2100 -> c4096 bucket): the tuned
+        # bucket neighbor with fewer cols (1100 -> c2048 bucket): the tuned
         # tile clamps to the neighbor's own padded width instead of
         # inheriting the full-bucket-width tile
+        registry.record_tuned("softmax", 64, 2048, jnp.float32, (64, 2048),
+                              path=cache)
+        assert on.resolve_blocks("softmax", 64, 2048) == (64, 2048)
+        assert on.resolve_blocks("softmax", 64, 1100) == (64, 1152)
+        # a tuned tile wider than 2048 columns clamps to 2048: a wider
+        # softmax block outgrows v5e's scoped VMEM
         registry.record_tuned("softmax", 64, 4096, jnp.float32, (64, 4096),
                               path=cache)
-        assert on.resolve_blocks("softmax", 64, 4096) == (64, 4096)
-        assert on.resolve_blocks("softmax", 64, 2100) == (64, 2176)
+        assert on.resolve_blocks("softmax", 64, 4096) == (64, 2048)
+        assert on.resolve_blocks("softmax", 64, 2100) == (64, 2048)
 
     def test_tuned_blocks_still_exact(self, tmp_path):
         """Whatever the tuner picks, results must match the oracle."""

@@ -216,6 +216,37 @@ class TestShardedEngineParity:
         assert "SHARDED_INIT_OK" in out
 
     @pytest.mark.slow
+    def test_paged_pool_made_in_its_shards(self):
+        """Under a mesh each device makes only its own shard of the page
+        arena: no whole arena lands on one device to be moved (a full
+        arena there does not fit beside a 14B model's share of the
+        weights).  An array made eagerly and then moved into its
+        sharding would raise here."""
+        out = _run("""
+            import dataclasses
+            import jax
+            from repro.configs import get_config
+            from repro.distributed import sharding as sh
+            from repro.launch.mesh import make_serving_mesh
+            from repro.serving import kv_cache
+
+            mesh = make_serving_mesh((1, 4))
+            cfg = dataclasses.replace(get_config("qwen2.5-14b").reduced(),
+                                      n_kv_heads=4)
+            with jax.transfer_guard("disallow_explicit"):
+                pool = kv_cache.init_paged_pool(cfg, 4, 64, 4, page_size=8,
+                                                pages=20, mesh=mesh)
+            want = sh.named(sh.pool_specs(pool, cfg, mesh), mesh)
+            got = jax.tree.map(lambda x: x.sharding, pool)
+            assert jax.tree.leaves(got) == jax.tree.leaves(want)
+            k = pool["kv"]["k"]
+            assert k.addressable_shards[0].data.shape[3] == \\
+                cfg.n_kv_heads // 4
+            print("POOL_IN_SHARDS_OK")
+        """)
+        assert "POOL_IN_SHARDS_OK" in out
+
+    @pytest.mark.slow
     def test_mla_parity_replicated_pool(self):
         """MLA (latent-cache) family under the same mesh: pool replicated,
         params TP through wkv_b — tokens still bit-identical."""
