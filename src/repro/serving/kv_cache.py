@@ -299,17 +299,11 @@ def init_paged_pool(cfg: ModelConfig, slots: int, max_len: int, tp: int = 1,
     if pages is None:
         pages = 1 + slots * (n_tab + n_xtab)
     if mesh is not None:
-        # a whole arena made on one device first need not fit beside
-        # that device's share of the weights
-        from repro.distributed import sharding as _sh  # lazy import
-
-        make = functools.partial(
+        return shard_pool(functools.partial(
             init_paged_pool, cfg, slots, max_len, tp, page_size=page_size,
             pages=pages, page_dtype=page_dtype,
-            scale_granularity=scale_granularity, cross_len=cross_len)
-        shapes = jax.eval_shape(make)
-        return jax.jit(make, out_shardings=_sh.named(
-            _sh.pool_specs(shapes, cfg, mesh), mesh))()
+            scale_granularity=scale_granularity, cross_len=cross_len),
+            cfg, mesh)
     dt = cache_dtype(cfg)
     hd = cfg.resolved_head_dim()
     ls = cfg.n_layers
@@ -344,15 +338,18 @@ def init_paged_pool(cfg: ModelConfig, slots: int, max_len: int, tp: int = 1,
     return pool
 
 
-def shard_pool(pool: dict, cfg: ModelConfig, mesh) -> dict:
-    """Lay a serving pool (paged or strip) out across ``mesh`` per
+def shard_pool(make, cfg: ModelConfig, mesh) -> dict:
+    """Build a serving pool (paged or strip) laid out across ``mesh`` per
     :func:`repro.distributed.sharding.pool_specs` — KV-head axis of the
     arenas over ``model``, slot/ssm axes over the data axes, page table
-    and lengths replicated.  Idempotent on already-placed pools."""
+    and lengths replicated.  ``make()`` returns the unsharded pool; it
+    runs under one jit with those shardings as its outputs, so each
+    device makes only its own shard (a whole arena made on one device
+    first need not fit beside that device's share of the weights)."""
     from repro.distributed import sharding as _sh  # lazy: serving↛distributed
 
-    return jax.device_put(pool, _sh.named(_sh.pool_specs(pool, cfg, mesh),
-                                          mesh))
+    specs = _sh.pool_specs(jax.eval_shape(make), cfg, mesh)
+    return jax.jit(make, out_shardings=_sh.named(specs, mesh))()
 
 
 def quantize_symmetric(x, axes):

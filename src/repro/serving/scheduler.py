@@ -294,10 +294,10 @@ class ContinuousBatchingEngine:
             self.slot_cross_pages: list[list[int]] = [[] for _ in
                                                       range(self.n_slots)]
         else:
-            self.pool = kv_cache.init_slot_pool(cfg, self.n_slots,
-                                                self.max_len, model.tp)
-            if mesh is not None:
-                self.pool = kv_cache.shard_pool(self.pool, cfg, mesh)
+            make = functools.partial(kv_cache.init_slot_pool, cfg,
+                                     self.n_slots, self.max_len, model.tp)
+            self.pool = (make() if mesh is None
+                         else kv_cache.shard_pool(make, cfg, mesh))
 
         # host-RAM swap tier: under page pressure a cold slot's pages move
         # to host RAM (bit-exact, scale sidecars included) instead of being

@@ -247,6 +247,33 @@ class TestShardedEngineParity:
         assert "POOL_IN_SHARDS_OK" in out
 
     @pytest.mark.slow
+    def test_strip_pool_made_in_its_shards(self):
+        """The slot-strip pool goes through the same ``shard_pool``: made
+        in its shards under one jit, never moved whole."""
+        out = _run("""
+            import dataclasses
+            import functools
+            import jax
+            from repro.configs import get_config
+            from repro.distributed import sharding as sh
+            from repro.launch.mesh import make_serving_mesh
+            from repro.serving import kv_cache
+
+            mesh = make_serving_mesh((1, 4))
+            cfg = dataclasses.replace(get_config("qwen2.5-14b").reduced(),
+                                      n_kv_heads=4)
+            make = functools.partial(kv_cache.init_slot_pool, cfg, 2, 64, 4)
+            with jax.transfer_guard("disallow_explicit"):
+                pool = kv_cache.shard_pool(make, cfg, mesh)
+            want = sh.named(sh.pool_specs(pool, cfg, mesh), mesh)
+            got = jax.tree.map(lambda x: x.sharding, pool)
+            assert jax.tree.leaves(got) == jax.tree.leaves(want)
+            assert not pool["kv"]["k"].sharding.is_fully_replicated
+            print("STRIP_IN_SHARDS_OK")
+        """)
+        assert "STRIP_IN_SHARDS_OK" in out
+
+    @pytest.mark.slow
     def test_mla_parity_replicated_pool(self):
         """MLA (latent-cache) family under the same mesh: pool replicated,
         params TP through wkv_b — tokens still bit-identical."""
